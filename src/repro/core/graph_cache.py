@@ -16,14 +16,30 @@ mirroring the paper's Figure 5 categories:
 
 Every compile is timed and the (read_cache_s, compile_s, source) triple
 is what benchmarks/recovery_time.py reports.
+
+The on-disk tier lives where ``JAX_COMPILATION_CACHE_DIR`` says when it
+is set (JAX reads it itself; nothing here sets another directory), and
+otherwise at one fixed path inside the checkout, ``.jax_cache/``.  The
+path is part of the cache's key, so it never moves with a workdir.
 """
 from __future__ import annotations
 
+import os
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
+
+from repro.paths import REPO_ROOT
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = str(REPO_ROOT / ".jax_cache")
+
+
+def persistent_cache_dir() -> str:
+    """Where the persistent compilation cache lives (see module doc)."""
+    return os.environ.get(CACHE_ENV) or DEFAULT_CACHE_DIR
 
 
 @dataclass
@@ -35,11 +51,13 @@ class CompileTiming:
 
 
 class GraphCache:
-    def __init__(self, persist_dir: Optional[str] = None):
-        """persist_dir: enables the on-disk compilation cache tier."""
-        self.persist_dir = persist_dir
-        if persist_dir:
-            jax.config.update("jax_compilation_cache_dir", persist_dir)
+    def __init__(self, persist: bool = True):
+        """persist: enables the on-disk compilation cache tier."""
+        self.persist_dir = persistent_cache_dir() if persist else None
+        if persist:
+            if not os.environ.get(CACHE_ENV):
+                jax.config.update("jax_compilation_cache_dir",
+                                  self.persist_dir)
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         self._exec: Dict[Tuple, Any] = {}

@@ -192,12 +192,8 @@ class RecoveryManager:
             f"graph for domain v{eng.domain.version}: {tm.source} "
             f"(precompiled hit={key_hit_before})")
 
-        # resume + integrity check
-        with _T(report, "other"):
-            if eng.cfg.moe is not None:
-                checks, alive = eng.expert_integrity()
-                report.actions.append(
-                    f"expert shards alive={alive}")
+        if eng.cfg.moe is not None:
+            report.actions.append(f"expert shards alive={eng.shard_alive}")
         return report
 
     # -- helpers ----------------------------------------------------------------------

@@ -22,6 +22,8 @@ Kernels:
   ``MoERuntime``, so ReviveMoE recovery (replica drop / expert mask)
   stays a data mutation with zero recompiles.
 
-``compat.py`` shims Pallas API renames across JAX versions
-(``TPUCompilerParams`` vs ``CompilerParams``).
+Every kernel passes ``pltpu.CompilerParams`` directly.
+``paged_attention``, ``moe_fused``
+and ``decode_megakernel`` are compiled for a described v5e at
+qwen2-moe-a2.7b widths by ``tests/test_tpu_compile.py``.
 """

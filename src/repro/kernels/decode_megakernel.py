@@ -71,23 +71,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro.kernels.moe_fused import f_block
 
 NEG_INF = -1e30
 
 
-def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
+def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref, l2p_ref, rcnt_ref,
                      q_ref, k_ref, v_ref, x_ref, wpost_ref, ln2_ref,
-                     router_ref, l2p_ref, rcnt_ref, mask_ref,
+                     router_ref, mask_ref,
                      sgate_ref, sup_ref, sdown_ref,
                      gate_ref, up_ref, down_ref,
                      y_ref, h2_ref,
                      acc_ref, m_ref, l_ref, o_ref, ssq_ref, lg_ref,
                      xs_ref, accm_ref, hg_ref, hu_ref, hgs_ref, hus_ref,
+                     yf_ref, h2f_ref,
                      sel_ref, wsel_ref, tok_ref, wgt_ref, cnt_ref, *,
                      bs: int, n_attn: int, nd: int, nf: int, ns: int,
                      cap: int, top_k: int, e_local: int, e_log: int,
-                     scale: float, eps: float, d_model: int, block_d: int):
+                     n_rep: int, scale: float, eps: float, d_model: int,
+                     block_d: int):
+    # Scalar tables live in SMEM: the scalar-prefetched paging arrays,
+    # expert_offset and MoERuntime l2p (flat, n_rep per logical
+    # expert) / replica_count, plus the routing scratch sel/wsel (flat
+    # B*top_k) and the per-expert slot tables tok/wgt (flat E*cap).
+    # Rows addressed by a runtime index (token gathers, the combine)
+    # use the f32 VMEM copies yf/h2f of the (B, Dp) activations; y and
+    # h2 are written from them.
     t = pl.program_id(0)
     B = y_ref.shape[0]
     attn_steps = B * n_attn
@@ -153,10 +162,9 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
         o_flat = o_ref[...].astype(x_ref.dtype)           # (B, H*Da)
         proj = jnp.dot(o_flat, wpost_ref[...],
                        preferred_element_type=jnp.float32)  # (B, Db)
-        yb = x_ref[...] + proj.astype(y_ref.dtype)
-        y_ref[:, pl.ds(dp * block_d, block_d)] = yb
-        sq = jnp.sum(jnp.square(yb.astype(jnp.float32)), axis=-1,
-                     keepdims=True)
+        yb = (x_ref[...] + proj.astype(y_ref.dtype)).astype(jnp.float32)
+        yf_ref[:, pl.ds(dp * block_d, block_d)] = yb
+        sq = jnp.sum(jnp.square(yb), axis=-1, keepdims=True)
 
         @pl.when(dp == 0)
         def _():
@@ -170,10 +178,11 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
     @pl.when((t >= r0) & (t < s0))
     def _norm_route():
         dr = t - r0
-        yb = y_ref[:, pl.ds(dr * block_d, block_d)].astype(jnp.float32)
+        yb = yf_ref[:, pl.ds(dr * block_d, block_d)]
         rs = jax.lax.rsqrt(ssq_ref[...] / d_model + eps)  # (B, 1)
         h2b = (yb * rs).astype(h2_ref.dtype) * ln2_ref[...]
         h2_ref[:, pl.ds(dr * block_d, block_d)] = h2b
+        h2f_ref[:, pl.ds(dr * block_d, block_d)] = h2b.astype(jnp.float32)
         contrib = jnp.dot(h2b, router_ref[...],
                           preferred_element_type=jnp.float32)  # (B, E_log)
 
@@ -194,21 +203,38 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
             iota_e = jax.lax.broadcasted_iota(jnp.int32, (B, e_log), 1)
             remaining = gates
             wsum = jnp.zeros((B, 1), jnp.float32)
+            sels, mvs = [], []
             for kk in range(top_k):  # k argmax passes; ties -> lowest id,
                 mv = jnp.max(remaining, axis=-1, keepdims=True)  # as top_k
                 sk = jnp.min(jnp.where(remaining >= mv, iota_e, e_log),
                              axis=-1, keepdims=True)
-                sel_ref[:, kk] = sk[:, 0]
-                wsel_ref[:, kk] = mv[:, 0]
+                sels.append(sk)
+                mvs.append(mv)
                 wsum = wsum + mv
                 remaining = jnp.where(iota_e == sk, -1.0, remaining)
-            wsel_ref[...] = wsel_ref[...] / jnp.maximum(wsum, 1e-9)
+            wn = [mv / jnp.maximum(wsum, 1e-9) for mv in mvs]
+            iota_b = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)
+
+            def _spill(b, _):
+                # (B, 1) vector -> row b as an SMEM scalar (a one-hot
+                # reduction: exactly one row contributes)
+                row = iota_b == b
+                for kk in range(top_k):
+                    sel_ref[b * top_k + kk] = jnp.sum(
+                        jnp.where(row, sels[kk], 0))
+                    wsel_ref[b * top_k + kk] = jnp.sum(
+                        jnp.where(row, wn[kk], 0.0))
+                return 0
+            jax.lax.fori_loop(0, B, _spill, 0)
 
             # per-expert slot tables: the sequential scan is the decode-
             # shaped sort pre-pass (token order == stable-sort order, so
             # drop semantics match moe_group_tokens exactly)
-            tok_ref[...] = jnp.zeros_like(tok_ref)
-            wgt_ref[...] = jnp.zeros_like(wgt_ref)
+            def _zero_slot(i, _):
+                tok_ref[i] = 0
+                wgt_ref[i] = 0.0
+                return 0
+            jax.lax.fori_loop(0, e_local * cap, _zero_slot, 0)
 
             def _zero(i, _):
                 cnt_ref[i] = 0
@@ -220,11 +246,11 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
             def _group(n, _):
                 b = n // top_k
                 kk = n % top_k
-                s = sel_ref[b, kk]
-                w = wsel_ref[b, kk]
-                rc = rcnt_ref[0, s]
+                s = sel_ref[n]
+                w = wsel_ref[n]
+                rc = rcnt_ref[s]
                 rep = jax.lax.rem(b + kk, jnp.maximum(rc, 1))
-                ph = l2p_ref[s, rep]
+                ph = l2p_ref[s * n_rep + rep]
                 e = ph - off
                 ok = (e >= 0) & (e < e_local) & (rc > 0)
                 ec = jnp.clip(e, 0, e_local - 1)
@@ -233,8 +259,8 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
 
                 @pl.when(ok)
                 def _():
-                    tok_ref[ec, c] = b
-                    wgt_ref[ec, c] = w
+                    tok_ref[ec * cap + c] = b
+                    wgt_ref[ec * cap + c] = w
                     cnt_ref[ec] = c + 1
 
                 return 0
@@ -275,8 +301,7 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
             contrib = jnp.dot(hgs_ref[...].astype(h2_ref.dtype),
                               sdown_ref[...],
                               preferred_element_type=jnp.float32)
-            y_ref[:, pl.ds(d * block_d, block_d)] += contrib.astype(
-                y_ref.dtype)
+            yf_ref[:, pl.ds(d * block_d, block_d)] += contrib
 
     # ---- phase M: grouped SwiGLU FFN + weighted scatter-combine -------
     @pl.when(t >= m0)
@@ -294,17 +319,17 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
             accm_ref[...] = jnp.zeros_like(accm_ref)
 
             def body(i, _):
-                tkn = tok_ref[e, i]
-                live = wgt_ref[e, i] != 0.0
-                row = h2_ref[tkn, :]
-                xs_ref[i, :] = jnp.where(live, row, 0.0).astype(
-                    xs_ref.dtype)
+                tkn = tok_ref[e * cap + i]
+                live = wgt_ref[e * cap + i] != 0.0
+                row = h2f_ref[pl.ds(tkn, 1), :]
+                xs_ref[pl.ds(i, 1), :] = jnp.where(live, row, 0.0)
                 return 0
             jax.lax.fori_loop(0, cap, body, 0)
 
         @pl.when(is_in)
         def _contract():
-            xg = xs_ref[:, pl.ds(d * block_d, block_d)]   # (cap, Db)
+            xg = xs_ref[:, pl.ds(d * block_d, block_d)].astype(
+                gate_ref.dtype)                           # (cap, Db)
             cg = jnp.dot(xg, gate_ref[0],
                          preferred_element_type=jnp.float32)
             cu = jnp.dot(xg, up_ref[0],
@@ -326,7 +351,7 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
             def _():
                 hg_ref[...] = jax.nn.silu(hg_ref[...]) * hu_ref[...]
 
-            contrib = jnp.dot(hg_ref[...].astype(xs_ref.dtype),
+            contrib = jnp.dot(hg_ref[...].astype(down_ref.dtype),
                               down_ref[0],
                               preferred_element_type=jnp.float32)
             accm_ref[:, pl.ds(d * block_d, block_d)] += contrib
@@ -334,16 +359,19 @@ def _megastep_kernel(bt_ref, sl_ref, st_ref, off_ref,
         @pl.when(u2 == per_e - 1)
         def _combine():
             def body(i, _):
-                w = wgt_ref[e, i]
+                w = wgt_ref[e * cap + i]
 
                 @pl.when(w != 0.0)
                 def _():
-                    tkn = tok_ref[e, i]
-                    y_ref[tkn, :] += (w * accm_ref[i, :]).astype(
-                        y_ref.dtype)
+                    tkn = tok_ref[e * cap + i]
+                    yf_ref[pl.ds(tkn, 1), :] += w * accm_ref[pl.ds(i, 1), :]
 
                 return 0
             jax.lax.fori_loop(0, cap, body, 0)
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _emit_y():
+        y_ref[...] = yf_ref[...].astype(y_ref.dtype)
 
 
 def decode_megastep_pallas(q, k_pool, v_pool, block_table, seq_lens,
@@ -374,7 +402,7 @@ def decode_megastep_pallas(q, k_pool, v_pool, block_table, seq_lens,
     F = gate_w.shape[-1]
     scale = 1.0 / (Da ** 0.5)
 
-    Fb = min(block_f, F)
+    Fb = f_block(F, block_f)
     Fp = ((F + Fb - 1) // Fb) * Fb
     if Fp != F:
         gate_w = jnp.pad(gate_w, ((0, 0), (0, 0), (0, Fp - F)))
@@ -406,7 +434,7 @@ def decode_megastep_pallas(q, k_pool, v_pool, block_table, seq_lens,
         shared_down = jnp.zeros((Fsb, Db), x.dtype)
     else:
         Fs = shared_gate.shape[1]
-        Fsb = min(block_f, Fs)
+        Fsb = f_block(Fs, block_f)
         Fsp = ((Fs + Fsb - 1) // Fsb) * Fsb
         ns = Fsp // Fsb
         shared_gate = jnp.pad(shared_gate,
@@ -450,50 +478,38 @@ def decode_megastep_pallas(q, k_pool, v_pool, block_table, seq_lens,
     kernel = functools.partial(
         _megastep_kernel, bs=bs, n_attn=n_attn, nd=nd, nf=nf, ns=ns,
         cap=cap, top_k=top_k, e_local=E, e_log=e_log, scale=scale,
-        eps=eps, d_model=D, block_d=Db)
+        n_rep=l2p.shape[1], eps=eps, d_model=D, block_d=Db)
+    # index maps see the grid index then the six scalar-prefetch refs
+    # (bt, sl, st, off, l2p, rcnt); only the KV pages read one (bt)
+    def _kv(t, bt, *_):
+        b, j = _ab(t)
+        return (bt[b, j], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=6,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, H, Da),
-                         lambda t, bt, sl, st, off: (_ab(t)[0], 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, Da),
-                         lambda t, bt, sl, st, off:
-                         (bt[_ab(t)[0], _ab(t)[1]], 0, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv, Da),
-                         lambda t, bt, sl, st, off:
-                         (bt[_ab(t)[0], _ab(t)[1]], 0, 0, 0)),
-            pl.BlockSpec((B, Db), lambda t, bt, sl, st, off: (0, _dp(t))),
-            pl.BlockSpec((H * Da, Db),
-                         lambda t, bt, sl, st, off: (0, _dp(t))),
-            pl.BlockSpec((1, Db), lambda t, bt, sl, st, off: (0, _dr(t))),
-            pl.BlockSpec((Db, e_log),
-                         lambda t, bt, sl, st, off: (_dr(t), 0)),
-            pl.BlockSpec(l2p.shape, lambda t, bt, sl, st, off: (0, 0)),
-            pl.BlockSpec((1, e_log), lambda t, bt, sl, st, off: (0, 0)),
-            pl.BlockSpec((1, e_log), lambda t, bt, sl, st, off: (0, 0)),
-            pl.BlockSpec((Db, Fsb),
-                         lambda t, bt, sl, st, off:
-                         (_sfd(t)[1], _sfd(t)[0])),
-            pl.BlockSpec((Db, Fsb),
-                         lambda t, bt, sl, st, off:
-                         (_sfd(t)[1], _sfd(t)[0])),
-            pl.BlockSpec((Fsb, Db),
-                         lambda t, bt, sl, st, off:
-                         (_sfd(t)[0], _sfd(t)[1])),
+            pl.BlockSpec((1, H, Da), lambda t, *_: (_ab(t)[0], 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, Da), _kv),
+            pl.BlockSpec((1, bs, Hkv, Da), _kv),
+            pl.BlockSpec((B, Db), lambda t, *_: (0, _dp(t))),
+            pl.BlockSpec((H * Da, Db), lambda t, *_: (0, _dp(t))),
+            pl.BlockSpec((1, Db), lambda t, *_: (0, _dr(t))),
+            pl.BlockSpec((Db, e_log), lambda t, *_: (_dr(t), 0)),
+            pl.BlockSpec((1, e_log), lambda t, *_: (0, 0)),
+            pl.BlockSpec((Db, Fsb), lambda t, *_: (_sfd(t)[1], _sfd(t)[0])),
+            pl.BlockSpec((Db, Fsb), lambda t, *_: (_sfd(t)[1], _sfd(t)[0])),
+            pl.BlockSpec((Fsb, Db), lambda t, *_: (_sfd(t)[0], _sfd(t)[1])),
             pl.BlockSpec((1, Db, Fb),
-                         lambda t, bt, sl, st, off:
-                         (_efd(t)[0], _efd(t)[2], _efd(t)[1])),
+                         lambda t, *_: (_efd(t)[0], _efd(t)[2], _efd(t)[1])),
             pl.BlockSpec((1, Db, Fb),
-                         lambda t, bt, sl, st, off:
-                         (_efd(t)[0], _efd(t)[2], _efd(t)[1])),
+                         lambda t, *_: (_efd(t)[0], _efd(t)[2], _efd(t)[1])),
             pl.BlockSpec((1, Fb, Db),
-                         lambda t, bt, sl, st, off:
-                         (_efd(t)[0], _efd(t)[1], _efd(t)[2])),
+                         lambda t, *_: (_efd(t)[0], _efd(t)[1], _efd(t)[2])),
         ],
         out_specs=[
-            pl.BlockSpec((B, Dp), lambda t, bt, sl, st, off: (0, 0)),
-            pl.BlockSpec((B, Dp), lambda t, bt, sl, st, off: (0, 0)),
+            pl.BlockSpec((B, Dp), lambda t, *_: (0, 0)),
+            pl.BlockSpec((B, Dp), lambda t, *_: (0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, Da), jnp.float32),    # attention accumulator
@@ -502,16 +518,18 @@ def decode_megastep_pallas(q, k_pool, v_pool, block_table, seq_lens,
             pltpu.VMEM((B, H * Da), jnp.float32),  # attention outputs
             pltpu.VMEM((B, 1), jnp.float32),     # norm sum of squares
             pltpu.VMEM((B, e_log), jnp.float32),  # router logit accum
-            pltpu.VMEM((cap, Dp), x.dtype),      # gathered expert rows
+            pltpu.VMEM((cap, Dp), jnp.float32),  # gathered expert rows
             pltpu.VMEM((cap, Dp), jnp.float32),  # FFN accumulator
             pltpu.VMEM((cap, Fb), jnp.float32),  # expert gate hidden
             pltpu.VMEM((cap, Fb), jnp.float32),  # expert up hidden
             pltpu.VMEM((B, Fsb), jnp.float32),   # shared gate hidden
             pltpu.VMEM((B, Fsb), jnp.float32),   # shared up hidden
-            pltpu.VMEM((B, top_k), jnp.int32),   # selected logical ids
-            pltpu.VMEM((B, top_k), jnp.float32),  # renormalized weights
-            pltpu.VMEM((E, cap), jnp.int32),     # slot -> token row
-            pltpu.VMEM((E, cap), jnp.float32),   # slot combine weight
+            pltpu.VMEM((B, Dp), jnp.float32),    # block output y
+            pltpu.VMEM((B, Dp), jnp.float32),    # h2 rows for gathers
+            pltpu.SMEM((B * top_k,), jnp.int32),    # selected logical ids
+            pltpu.SMEM((B * top_k,), jnp.float32),  # renormalized weights
+            pltpu.SMEM((E * cap,), jnp.int32),   # slot -> token row
+            pltpu.SMEM((E * cap,), jnp.float32),  # slot combine weight
             pltpu.SMEM((E,), jnp.int32),         # per-expert fill count
         ],
     )
@@ -520,15 +538,15 @@ def decode_megastep_pallas(q, k_pool, v_pool, block_table, seq_lens,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Dp), x.dtype),
                    jax.ShapeDtypeStruct((B, Dp), x.dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       start_lens.astype(jnp.int32),
       jnp.asarray(expert_offset, jnp.int32).reshape(1),
+      l2p.astype(jnp.int32).reshape(-1), replica_count.astype(jnp.int32),
       q, k_pool, v_pool, x, w_post, ln2_w.reshape(1, Dp), router_w,
-      l2p.astype(jnp.int32), replica_count.astype(jnp.int32).reshape(
-          1, e_log), expert_mask.astype(jnp.int32).reshape(1, e_log),
+      expert_mask.astype(jnp.int32).reshape(1, e_log),
       shared_gate, shared_up, shared_down, gate_w, up_w, down_w)
     if Dp != D:
         y, h2 = y[:, :D], h2[:, :D]

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -116,7 +114,7 @@ def flash_prefill_pallas(q, k, v, *, causal: bool = True, block_q: int = 256,
             pltpu.VMEM((G, bq, 1), jnp.float32),
             pltpu.VMEM((G, bq, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -30,6 +30,11 @@ experts receives its k partial sums across k distinct grid steps.  HBM
 sees x once, y once, and two (E·cap) int32/f32 tables — no (E, cap, D)
 buffer, no unsort pass.
 
+The two tables are scalar-prefetched whole into SMEM (the kernel only
+reads them as scalars).  x and y ride in VMEM as float32: their rows are
+addressed by a runtime token index, which Mosaic allows only on 32-bit
+arrays.
+
 Current limitation (documented, not enforced): x and y ride in whole-
 array VMEM block specs, so very large prefill chunks should be split by
 the caller (the distributed path already chunks at
@@ -44,8 +49,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
 from repro.models.moe import group_by_expert
+
+
+def f_block(F: int, pref: int) -> int:
+    """FFN-axis block for a (D, F) weight page: the whole axis when it
+    fits ``pref``, else the largest multiple of 128 up to ``pref`` that
+    divides F (qwen2-moe's 1408 = 11 x 128 takes 128), so the expert bank
+    is never padded (a per-call copy of every weight); ``pref`` with
+    padding only when no such divisor exists."""
+    if F <= pref:
+        return F
+    for fb in range(pref - pref % 128, 0, -128):
+        if F % fb == 0:
+            return fb
+    return pref
 
 
 def moe_group_tokens(phys, alive, weights, *, expert_offset, e_local: int,
@@ -73,10 +91,13 @@ def moe_group_tokens(phys, alive, weights, *, expert_offset, e_local: int,
 
 def _moe_fused_kernel(tok_ref, wgt_ref, x_ref, g_ref, u_ref, d_ref, y_ref,
                       xs_ref, acc_ref, *, cb: int):
+    # tok_ref/wgt_ref are the whole (E, Cp) slot tables, scalar-prefetched
+    # into SMEM: every access below is a scalar read
     e = pl.program_id(0)
     c = pl.program_id(1)
     f = pl.program_id(2)
     nf = pl.num_programs(2)
+    c0 = c * cb
 
     @pl.when((e == 0) & (c == 0) & (f == 0))
     def _zero_out():
@@ -87,15 +108,15 @@ def _moe_fused_kernel(tok_ref, wgt_ref, x_ref, g_ref, u_ref, d_ref, y_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
         def body(i, _):
-            t = tok_ref[0, i]
-            live = wgt_ref[0, i] != 0.0
-            row = x_ref[t, :]
-            xs_ref[i, :] = jnp.where(live, row, 0.0).astype(xs_ref.dtype)
+            t = tok_ref[e, c0 + i]
+            live = wgt_ref[e, c0 + i] != 0.0
+            row = x_ref[pl.ds(t, 1), :]
+            xs_ref[pl.ds(i, 1), :] = jnp.where(live, row, 0.0)
             return 0
 
         jax.lax.fori_loop(0, cb, body, 0)
 
-    x = xs_ref[...]                                   # (Cb, D)
+    x = xs_ref[...].astype(g_ref.dtype)               # (Cb, D)
     g = g_ref[0]                                      # (D, Fb)
     u = u_ref[0]
     d = d_ref[0]                                      # (Fb, D)
@@ -107,12 +128,12 @@ def _moe_fused_kernel(tok_ref, wgt_ref, x_ref, g_ref, u_ref, d_ref, y_ref,
     @pl.when(f == nf - 1)
     def _combine():
         def body(i, _):
-            w = wgt_ref[0, i]
+            w = wgt_ref[e, c0 + i]
 
             @pl.when(w != 0.0)
             def _():
-                t = tok_ref[0, i]
-                y_ref[t, :] += (w * acc_ref[i, :]).astype(y_ref.dtype)
+                t = tok_ref[e, c0 + i]
+                y_ref[pl.ds(t, 1), :] += w * acc_ref[pl.ds(i, 1), :]
 
             return 0
 
@@ -140,7 +161,7 @@ def moe_fused_pallas(x, gate_w, up_w, down_w, weights, phys, alive, *,
         e_local=e_local, cap=cap)
 
     Cb = min(block_c, cap)
-    Fb = min(block_f, F)
+    Fb = f_block(F, block_f)
     Cp = ((cap + Cb - 1) // Cb) * Cb
     Fp = ((F + Fb - 1) // Fb) * Fb
     if Cp != cap:
@@ -152,25 +173,27 @@ def moe_fused_pallas(x, gate_w, up_w, down_w, weights, phys, alive, *,
         down_w = jnp.pad(down_w, ((0, 0), (0, Fp - F), (0, 0)))
 
     kernel = functools.partial(_moe_fused_kernel, cb=Cb)
-    y = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                        # tok_idx, wgt
         grid=(E, Cp // Cb, Fp // Fb),
         in_specs=[
-            pl.BlockSpec((1, Cb), lambda e, c, f: (e, c)),      # tok_idx
-            pl.BlockSpec((1, Cb), lambda e, c, f: (e, c)),      # wgt
-            pl.BlockSpec((T, D), lambda e, c, f: (0, 0)),       # x (whole)
-            pl.BlockSpec((1, D, Fb), lambda e, c, f: (e, 0, f)),
-            pl.BlockSpec((1, D, Fb), lambda e, c, f: (e, 0, f)),
-            pl.BlockSpec((1, Fb, D), lambda e, c, f: (e, f, 0)),
+            pl.BlockSpec((T, D), lambda e, c, f, tk, wg: (0, 0)),  # x whole
+            pl.BlockSpec((1, D, Fb), lambda e, c, f, tk, wg: (e, 0, f)),
+            pl.BlockSpec((1, D, Fb), lambda e, c, f, tk, wg: (e, 0, f)),
+            pl.BlockSpec((1, Fb, D), lambda e, c, f, tk, wg: (e, f, 0)),
         ],
-        out_specs=pl.BlockSpec((T, D), lambda e, c, f: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
+        out_specs=pl.BlockSpec((T, D), lambda e, c, f, tk, wg: (0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Cb, D), x.dtype),
+            pltpu.VMEM((Cb, D), jnp.float32),
             pltpu.VMEM((Cb, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+    )
+    y = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tok_idx, wgt, x, gate_w, up_w, down_w)
+    )(tok_idx, wgt, x.astype(jnp.float32), gate_w, up_w, down_w)
     return y.astype(x.dtype)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-
 
 def _ssm_scan_kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
                      h_ref, *, chunk: int):
@@ -78,7 +76,7 @@ def ssm_scan_pallas(u, dt, A, B_ssm, C_ssm, *, block_d: int = 256,
             jax.ShapeDtypeStruct((Bsz, d, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((db, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, A, B_ssm, C_ssm)

@@ -1,8 +1,13 @@
 """Serving launcher: FlowServe instance(s) with ReviveMoE recovery.
 
-Single instance:
+Single instance (smoke-size config, the CPU/CI default):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-moe-a2.7b \
       --mode disaggregated --requests 8 --inject-fault moe
+
+The registered config at its published widths, depth cut to N layers
+(``--layers``; what ``chip_smoke.py`` serves on one TPU):
+  PYTHONPATH=src python -m repro.launch.serve --layers 8 --mode collocated \
+      --requests 8 --inject-fault moe
 
 Fleet mode — N instances + K hot spares behind the cluster router, with
 restart-vs-revive-vs-spare arbitration and optional full-instance loss:
@@ -12,22 +17,70 @@ restart-vs-revive-vs-spare arbitration and optional full-instance loss:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import sys
+from typing import Optional
 
 import numpy as np
 
+from repro.paths import REPO_ROOT
+
+
+def model_config(arch: str, layers: Optional[int] = None):
+    """The smoke config when ``layers`` is None (CI, tests); otherwise the
+    registered config at its published widths with only the depth cut to
+    ``layers``."""
+    from repro.configs import get_config, get_smoke_config
+    if layers is None:
+        return get_smoke_config(arch)
+    cfg = dataclasses.replace(get_config(arch), num_layers=layers)
+    cfg.validate()
+    return cfg
+
+
+def default_workdir(cfg, seed: int) -> str:
+    """Weights directory inside the checkout, keyed by the config (name
+    and depth) and the seed, so weights of one config are never restored
+    into another."""
+    return os.path.join(REPO_ROOT / ".work",
+                        f"{cfg.name}-L{cfg.num_layers}-seed{seed}")
+
+
+def engine_config(cfg, *, mode: str = "collocated", num_dp: int = 2,
+                  num_moe: int = 2, decode_impl: Optional[str] = None,
+                  overlap: bool = False, workdir: Optional[str] = None,
+                  seed: int = 0):
+    """The serving engine's configuration, shared by this launcher and
+    ``chip_smoke.py``."""
+    from repro.serving.engine import EngineConfig
+    return EngineConfig(mode=mode, num_dp=num_dp, num_moe=num_moe,
+                        max_batch=4, max_seq=128, block_size=16,
+                        num_blocks=256, seed=seed,
+                        workdir=workdir or default_workdir(cfg, seed),
+                        decode_impl=decode_impl, overlap=overlap)
+
+
+def schedule_fault(eng, component: str, step: int, mode: str,
+                   num_dp: int) -> int:
+    """Schedule one mid-step L6 HBM-ECC fault on the device ``--inject-fault``
+    names: the first MoE rank (disaggregated) or DP rank 1.  Returns the
+    physical id."""
+    from repro.core.fault_codes import ErrorType, Severity
+    pid = (num_dp if component == "moe" and mode == "disaggregated"
+           else 1)
+    eng.injector.schedule(step, pid, severity=Severity.L6,
+                          error_type=ErrorType.HBM_ECC, component=component,
+                          mid_step=True)
+    return pid
+
 
 def _run_fleet(args, cfg) -> int:
-    from repro.core.fault_codes import ErrorType, Severity
     from repro.fleet import PoissonTraffic, build_fleet
-    from repro.serving.engine import EngineConfig
 
-    ec = EngineConfig(mode=args.mode, num_dp=args.num_dp,
-                      num_moe=args.num_moe, max_batch=4, max_seq=128,
-                      block_size=16, num_blocks=256,
-                      decode_impl=args.decode_impl,
-                      overlap=args.overlap,
-                      workdir=args.workdir)
+    ec = engine_config(cfg, mode=args.mode, num_dp=args.num_dp,
+                       num_moe=args.num_moe, decode_impl=args.decode_impl,
+                       overlap=args.overlap, workdir=args.workdir)
     if args.http is not None:
         # HTTP mode: arrivals come from clients, not a synthetic trace
         fleet = build_fleet(cfg, ec, instances=args.fleet,
@@ -50,12 +103,8 @@ def _run_fleet(args, cfg) -> int:
                         replenish_spares=args.replenish_spares,
                         kv_stream=not args.no_kv_stream)
     if args.inject_fault:
-        pid = (args.num_dp if args.inject_fault == "moe"
-               and args.mode == "disaggregated" else 1)
-        fleet.instances[0].engine.injector.schedule(
-            args.fault_step, pid, severity=Severity.L6,
-            error_type=ErrorType.HBM_ECC, component=args.inject_fault,
-            mid_step=True)
+        pid = schedule_fault(fleet.instances[0].engine, args.inject_fault,
+                             args.fault_step, args.mode, args.num_dp)
         print(f"scheduled {args.inject_fault} device fault on instance 0 "
               f"pid {pid} at engine step {args.fault_step}")
     lost = False
@@ -92,7 +141,13 @@ def main(argv=None):
     ap.add_argument("--inject-fault", default=None,
                     choices=[None, "attn", "moe"])
     ap.add_argument("--fault-step", type=int, default=5)
-    ap.add_argument("--workdir", default="/tmp/repro_serve")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="serve the registered config at its published "
+                    "widths with its depth cut to N layers (default: the "
+                    "smoke-size config)")
+    ap.add_argument("--workdir", default=None,
+                    help="weights directory (default: .work/<config> in "
+                    "the checkout)")
     ap.add_argument("--fleet", type=int, default=0, metavar="N",
                     help="run N instances behind the fleet router")
     ap.add_argument("--spares", type=int, default=0, metavar="K",
@@ -129,17 +184,14 @@ def main(argv=None):
     if args.http is not None and args.fleet == 0:
         args.fleet = 1              # the front end drives a FleetRouter
 
-    from repro.configs import get_smoke_config
-    from repro.core.fault_codes import ErrorType, Severity
-    from repro.serving.engine import EngineConfig, InferenceEngine
+    from repro.serving.engine import InferenceEngine
 
-    cfg = get_smoke_config(args.arch)
+    cfg = model_config(args.arch, args.layers)
     if args.fleet > 0:
         return _run_fleet(args, cfg)
-    ec = EngineConfig(mode=args.mode, num_dp=args.num_dp,
-                      num_moe=args.num_moe, max_batch=4, max_seq=128,
-                      block_size=16, num_blocks=256, workdir=args.workdir,
-                      decode_impl=args.decode_impl, overlap=args.overlap)
+    ec = engine_config(cfg, mode=args.mode, num_dp=args.num_dp,
+                       num_moe=args.num_moe, decode_impl=args.decode_impl,
+                       overlap=args.overlap, workdir=args.workdir)
     print(f"building engine: {args.arch} ({args.mode}, "
           f"{args.num_dp} DP + {args.num_moe if cfg.moe else 0} MoE ranks)")
     eng = InferenceEngine(cfg, ec)
@@ -151,11 +203,8 @@ def main(argv=None):
                        args.max_new) for _ in range(args.requests)]
 
     if args.inject_fault:
-        pid = (args.num_dp if args.inject_fault == "moe"
-               and args.mode == "disaggregated" else 1)
-        eng.injector.schedule(args.fault_step, pid, severity=Severity.L6,
-                              error_type=ErrorType.HBM_ECC,
-                              component=args.inject_fault, mid_step=True)
+        pid = schedule_fault(eng, args.inject_fault, args.fault_step,
+                             args.mode, args.num_dp)
         print(f"scheduled {args.inject_fault} fault on device {pid} "
               f"at step {args.fault_step}")
 

@@ -13,6 +13,7 @@ Two deployment modes (§2.2):
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from dataclasses import dataclass, field
@@ -34,8 +35,8 @@ from repro.models.model import Model
 from repro.serving.executor import DPExecutor, MoEExecutor, next_bucket
 from repro.serving.request import Request, RequestState
 from repro.serving.sampling import SamplingParams
-from repro.serving.weights_util import (assemble, expert_checksums,
-                                        split_experts)
+from repro.serving.weights_util import (bank_programs, split_experts,
+                                        update_rank_slice)
 from repro.training.checkpoint import restore_like, save_checkpoint
 
 
@@ -94,6 +95,13 @@ def _install_closure(axes_leaves, bucket: int):
     return fn
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted_init(cfg: ModelConfig, dtype):
+    # one traced program: the random draws never materialize in float32
+    # beside the weights, and equal (config, dtype) pairs share it
+    return jax.jit(Model(cfg, dtype=dtype).init)
+
+
 class _Ctx:
     """What an executor sees during compute: weights + compiled fns."""
 
@@ -129,7 +137,9 @@ class EngineConfig:
     workdir: str = "/tmp/repro_engine"
     policy: RecoveryPolicy = field(default_factory=RecoveryPolicy)
     precompile_failure_scenarios: bool = True
-    persist_cache_dir: Optional[str] = None
+    # weights and KV pools; a CPU test whose tolerance needs float32
+    # asks for it here
+    dtype: str = "bfloat16"
     heartbeat_timeout_steps: int = 2
     # override ModelConfig.moe_impl (e.g. 'fused' routes the MoE layer
     # through the fused Pallas dispatch->FFN->combine pipeline); None
@@ -193,6 +203,10 @@ class EngineConfig:
             raise ValueError(
                 f"EngineConfig.num_moe must be a non-negative int, "
                 f"got {self.num_moe!r}")
+        if self.dtype not in ("bfloat16", "float32"):
+            raise ValueError(
+                f"EngineConfig.dtype must be 'bfloat16' or 'float32', "
+                f"got {self.dtype!r}")
         if self.heartbeat_timeout_steps < 1:
             raise ValueError(
                 f"EngineConfig.heartbeat_timeout_steps must be >= 1, "
@@ -320,15 +334,13 @@ class InferenceEngine:
         with _Timer(t, "engine"):
             # paper baseline is a *cached* reinit: the compile cache lives
             # on disk (Dynamo/IR cache analogue = XLA persistent cache)
-            if ec.persist_cache_dir is None:
-                ec.persist_cache_dir = os.path.join(ec.workdir, "xla_cache")
             self.graph_cache = getattr(self, "graph_cache", None) or \
-                GraphCache(ec.persist_cache_dir)
+                GraphCache()
             self.injector = getattr(self, "injector", None) or FaultInjector()
             self.poller = AnnotationPoller(self.injector)
             self.monitor = HeartbeatMonitor(ec.heartbeat_timeout_steps)
             self.straggler = StragglerDetector()
-            self.model = Model(self.cfg)
+            self.model = Model(self.cfg, dtype=jnp.dtype(ec.dtype))
             from repro.serving.cache_ops import infer_paged_axes
             _, self.paged_axes = infer_paged_axes(
                 self.model, ec.num_blocks, ec.block_size)
@@ -337,37 +349,35 @@ class InferenceEngine:
 
         with _Timer(t, "generator"):
             # model instantiation + weight loading + KV warmup
+            # the device holds the weights once: ``self.params`` is the
+            # served tree, expert bank included (every rank alive)
             if os.path.exists(self.ckpt_path):
                 template = self.model.param_specs()
-                full_params = restore_like(self.ckpt_path, template)
-                full_params = jax.tree_util.tree_map(jnp.asarray, full_params)
+                self.params = jax.tree_util.tree_map(
+                    jnp.asarray, restore_like(self.ckpt_path, template))
             else:
-                full_params = self.model.init(
+                self.params = _jitted_init(self.cfg, self.model.dtype)(
                     jax.random.PRNGKey(ec.seed))
-                save_checkpoint(self.ckpt_path, full_params)
+                save_checkpoint(self.ckpt_path, self.params)
             self.ep_size = (ec.num_moe if ec.mode == "disaggregated"
                             else ec.num_dp) if self.cfg.moe else 0
             if self.cfg.moe is not None:
-                self.base_params, self.shards = split_experts(
-                    full_params, self.ep_size)
+                self.shards = split_experts(self.params, self.ep_size)
                 from repro.serving.weights_util import save_shard_checkpoints
                 save_shard_checkpoints(ec.workdir, self.shards)
                 self.expert_map = ExpertMap(self.cfg.moe, self.ep_size)
                 self.runtime = self.expert_map.runtime()
-                self.shard_alive = [True] * self.ep_size
-                self.params = assemble(self.base_params, self.shards,
-                                       self.shard_alive)
+                # the host shard whose weights each rank's slice holds
+                self.bank_shards = list(self.shards)
                 self.dense_groups = (
                     DenseFFNGroups(max(2, self.ep_size // 2))
                     if self.cfg.moe.first_k_dense else None)
             else:
-                self.base_params, self.shards = full_params, []
+                self.shards = []
                 self.expert_map = None
                 self.runtime = None
-                self.shard_alive = []
-                self.params = full_params
+                self.bank_shards = []
                 self.dense_groups = None
-            del full_params
 
         with _Timer(t, "executor_processes"):
             self.dp_executors: List[DPExecutor] = []
@@ -498,9 +508,27 @@ class InferenceEngine:
                 self.graph_cache.get_or_compile(key, fn,
                                                 self._arg_specs(phase))
 
+    def _bank_fn(self, key: tuple):
+        """Compiled in-place rank-slice update of the expert bank (keys of
+        ``weights_util.bank_programs``); like the install scatter it has
+        no collectives, so one executable serves every domain version
+        and every rank."""
+        if key in self.graph_cache:
+            return self.graph_cache.get_or_compile(key, None, None)[0]
+        fn, specs = bank_programs(self.params, self.ep_size)[key]
+        return self.graph_cache.get_or_compile(key, fn, specs,
+                                               donate_argnums=(0,))[0]
+
     def _precompile_failure_graphs(self) -> None:
         """§3.6: precompile graphs for the anticipated failure scenario
         (post-failure domain version), so recovery does a cached compile."""
+        if self.cfg.moe is not None:
+            # a lost rank zeroes its bank slice, a restored one writes it
+            for key, (fn, specs) in bank_programs(self.params,
+                                                  self.ep_size).items():
+                if key not in self.graph_cache:
+                    self.graph_cache.precompile(key, fn, specs,
+                                                donate_argnums=(0,))
         v = self.domain.version + 1
         self.graph_cache.precompile(
             ("decode", v, None), _decode_closure(self.model, v),
@@ -1086,18 +1114,29 @@ class InferenceEngine:
 
     # -- weight assembly -----------------------------------------------------------------
 
-    def reassemble_params(self) -> None:
+    @property
+    def shard_alive(self) -> List[bool]:
+        return [s is not None for s in self.bank_shards]
+
+    def reassemble_params(self, reload=()) -> None:
+        """Bring the device expert bank in line with the live shards, in
+        place: a rank whose owner died has its slice zeroed, and a rank
+        whose owner now holds another host shard (role switch, rejoin —
+        each loads its shard from disk) or is listed in ``reload``
+        (rebalanced replica slots) has that shard written into its slice.
+        The old buffers are donated, so no second bank is ever allocated
+        and nothing else is re-uploaded."""
         if self.cfg.moe is None:
             return
-        shard_arrays = []
+        per = self.expert_map.slots_per_rank
         for r in range(self.ep_size):
             owner = self._shard_owner(r)
-            shard_arrays.append(owner.shard if owner is not None else None)
-        self.shard_alive = [s is not None for s in shard_arrays]
-        self.params = assemble(self.base_params,
-                               [s if s is not None else self.shards[r]
-                                for r, s in enumerate(shard_arrays)],
-                               self.shard_alive)
+            shard = owner.shard if owner is not None else None
+            if shard is not self.bank_shards[r] or (
+                    shard is not None and r in reload):
+                self.params = update_rank_slice(
+                    self.params, self._bank_fn, r, per, shard)
+                self.bank_shards[r] = shard
 
     def _shard_owner(self, ep_rank: int):
         """The executor currently hosting this EP rank's shard (or None)."""
@@ -1121,6 +1160,7 @@ class InferenceEngine:
             return {}
         emap = self.expert_map
         moves = emap.rebalance_replicas(usage_counts)
+        copied = set()
         for slot, logical in moves.items():
             # copy weights from an alive source slot of `logical`
             sources = [s for s in emap.replicas_of(logical) if s != slot]
@@ -1135,15 +1175,10 @@ class InferenceEngine:
             s_loc, d_loc = src % per, slot % per
             for key, arr in dst_owner.shard.items():
                 arr[:, d_loc] = src_owner.shard[key][:, s_loc]
+            copied.add(emap.rank_of_slot(slot))
         self.runtime = emap.runtime()
-        self.reassemble_params()
+        self.reassemble_params(reload=copied)
         return moves
-
-    def expert_integrity(self) -> Tuple[List[float], List[bool]]:
-        shard_arrays = [self._shard_owner(r).shard
-                        if self._shard_owner(r) else None
-                        for r in range(self.ep_size)]
-        return expert_checksums(shard_arrays), self.shard_alive
 
     # -- baseline: full instance reinitialization (Fig. 1) ------------------------------
 
@@ -1165,6 +1200,11 @@ class InferenceEngine:
         # process death: in-memory executables are gone (the on-disk
         # persistent compile cache survives — that's the "cached" part)
         self.graph_cache.invalidate(lambda k: True)
+        # ... and so is every device buffer: drop the weights and KV pools
+        # before the rebuild loads them again, or the device would hold
+        # two copies of the model at once
+        self.params = None
+        self.dp_executors, self.moe_executors = [], []
         t = self._build(first_time=False)
         # restore shard state for ranks that had died (weights came from
         # disk in _build's generator stage — that's the point of reinit)

@@ -50,8 +50,20 @@ def load_flat(path: str) -> Dict[str, np.ndarray]:
         return {k: z[k] for k in z.files if not k.startswith("__extra__/")}
 
 
+def as_dtype(arr: np.ndarray, dtype) -> np.ndarray:
+    """``arr`` as ``dtype``.  ``np.savez`` writes dtypes numpy does not
+    know (bfloat16) as raw ``|V`` bytes; those are viewed, bit-exact, as
+    the wanted dtype of the same width.  Anything else is cast."""
+    dtype = np.dtype(dtype)
+    if arr.dtype.kind == "V":
+        assert arr.dtype.itemsize == dtype.itemsize, (arr.dtype, dtype)
+        return arr.view(dtype)
+    return arr.astype(dtype, copy=False)
+
+
 def restore_like(path: str, template) -> Any:
-    """Restore a pytree shaped like ``template`` from the checkpoint."""
+    """Restore a pytree shaped like ``template`` from the checkpoint, in
+    the template's dtypes."""
     flat = load_flat(path)
     paths, tdef = jax.tree_util.tree_flatten_with_path(template)
     leaves = []
@@ -59,7 +71,7 @@ def restore_like(path: str, template) -> Any:
         key = "/".join(_key_str(k) for k in p)
         arr = flat[key]
         assert arr.shape == leaf.shape, (key, arr.shape, leaf.shape)
-        leaves.append(arr.astype(leaf.dtype))
+        leaves.append(as_dtype(arr, leaf.dtype))
     return tdef.unflatten(leaves)
 
 

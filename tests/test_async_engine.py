@@ -39,6 +39,9 @@ def _engine(tmp_path, sub, *, overlap=False, spec_window=0,
     cfg_fn = over.pop("cfg_fn", None)
     if cfg_fn:
         cfg = cfg_fn(cfg)
+    # overlap and fault replay are compared token for token with lockstep:
+    # float32 weights
+    over.setdefault("dtype", "float32")
     ec = EngineConfig(mode="collocated", num_dp=num_dp, max_batch=2,
                       max_seq=96, block_size=8, num_blocks=64,
                       workdir=str(tmp_path / sub), overlap=overlap,

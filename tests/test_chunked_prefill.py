@@ -31,6 +31,9 @@ def _engine(tmp_path, name="internlm2-20b", sub="e", **over):
             capacity_factor=8.0, min_capacity=64))
     if over.pop("windowed", False):
         cfg = dataclasses.replace(cfg, sliding_window=16)
+    # token-exact comparisons across computation orders (chunked vs
+    # serial, cached vs cold) need float32 weights
+    over.setdefault("dtype", "float32")
     ec = EngineConfig(mode="collocated", num_dp=1, max_batch=4,
                       max_seq=over.pop("max_seq", 64), block_size=8,
                       num_blocks=64, workdir=str(tmp_path / sub),

@@ -266,6 +266,8 @@ def test_megastep_zero_recompile_full_step():
 
 def _engine(tmp_path, sub, decode_impl=None, num_dp=1, **over):
     cfg = get_smoke_config("qwen2-moe-a2.7b")
+    # megakernel vs composed is compared token for token: float32 weights
+    over.setdefault("dtype", "float32")
     ec = EngineConfig(mode="collocated", num_dp=num_dp, max_batch=2,
                       max_seq=over.pop("max_seq", 64), block_size=8,
                       num_blocks=64, workdir=str(tmp_path / sub),

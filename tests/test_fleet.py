@@ -36,7 +36,10 @@ def fleet_cfg():
 
 def fleet_ecfg(workdir, **kw):
     base = dict(mode="disaggregated", num_dp=2, num_moe=2, max_batch=2,
-                max_seq=64, block_size=8, num_blocks=64, workdir=workdir)
+                max_seq=64, block_size=8, num_blocks=64, workdir=workdir,
+                # replayed and streamed migrations are compared token for
+                # token with unmigrated runs: float32 weights
+                dtype="float32")
     base.update(kw)
     return EngineConfig(**base)
 

@@ -114,5 +114,4 @@ def test_two_sequential_moe_failures(tmp_path, seed):
     eng.run(max_steps=300)
     assert len(eng.reports) == 2
     assert all(r.state.value == "finished" for r in reqs)
-    checks, alive = eng.expert_integrity()
-    assert all(alive)  # both failures ended with full weight integrity
+    assert all(eng.shard_alive)  # both failures ended with every shard back

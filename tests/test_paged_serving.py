@@ -362,7 +362,7 @@ def test_kv_stream_equals_reprefill_tokens(tmp_path):
                                      capacity_factor=8.0, min_capacity=64))
     ecfg = EngineConfig(mode="collocated", num_dp=1, max_batch=2,
                         max_seq=64, block_size=8, num_blocks=32,
-                        workdir=str(tmp_path),
+                        workdir=str(tmp_path), dtype="float32",
                         sampling=SamplingParams(temperature=0.8,
                                                 top_p=0.9, seed=7))
     src = InferenceEngine(cfg, ecfg)

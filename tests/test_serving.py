@@ -161,29 +161,41 @@ def test_checkpoint_roundtrip(tmp_path):
 
 
 def test_expert_shard_split_assemble_roundtrip():
-    from repro.serving.weights_util import assemble, split_experts
+    """Host shards slice the bank exactly; zeroing a rank's slice and
+    writing its shard back, both in place, restores the bank bit-exact
+    and touches nothing else."""
+    from repro.serving.weights_util import (bank_programs,
+                                            expert_leaf_keys,
+                                            is_expert_leaf, split_experts,
+                                            update_rank_slice)
     cfg = get_smoke_config("qwen2-moe-a2.7b")
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    from repro.serving.weights_util import is_expert_leaf
-    base, shards = split_experts(params, ep_size=2)
-    # base has no routed-expert weights (shared experts stay)
-    assert all(float(jnp.abs(l).sum()) == 0
-               for p, l in jax.tree_util.tree_flatten_with_path(base)[0]
-               if is_expert_leaf(p))
-    together = assemble(base, shards, [True, True])
-    for a, b in zip(jax.tree_util.tree_leaves(params),
-                    jax.tree_util.tree_leaves(together)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    want = [np.asarray(l) for l in jax.tree_util.tree_leaves(params)]
+    shards = split_experts(params, ep_size=2)
+    keys = expert_leaf_keys(params)
+    assert keys and all(set(sh) == set(keys) for sh in shards)
+    flat = dict((p, l) for p, l in
+                jax.tree_util.tree_flatten_with_path(params)[0])
+    E = next(l for p, l in flat.items() if is_expert_leaf(p)).shape[1]
+    per = E // 2
+    progs = {key: jax.jit(fn, donate_argnums=0).lower(*specs).compile()
+             for key, (fn, specs) in bank_programs(params, 2).items()}
     # dead shard -> zeros in its slice, rest intact
-    half = assemble(base, shards, [True, False])
-    leaves = {str(p): l for p, l in
-              jax.tree_util.tree_flatten_with_path(half)[0]}
-    gate = next(l for p, l in leaves.items()
-                if "moe" in p and "gate" in p)
-    E = gate.shape[1]
-    assert float(jnp.abs(gate[:, E // 2:]).sum()) == 0.0
-    assert float(jnp.abs(gate[:, : E // 2]).sum()) > 0.0
+    half = update_rank_slice(params, progs.__getitem__, 1, per)
+    for (p, l), w in zip(jax.tree_util.tree_flatten_with_path(half)[0],
+                         want):
+        l = np.asarray(l)
+        if is_expert_leaf(p):
+            assert np.abs(l[:, per:].astype(np.float32)).sum() == 0.0
+            np.testing.assert_array_equal(l[:, :per], w[:, :per])
+            np.testing.assert_array_equal(
+                l[:, per:], np.zeros_like(w[:, per:]))
+        else:
+            np.testing.assert_array_equal(l, w)
+    together = update_rank_slice(half, progs.__getitem__, 1, per, shards[1])
+    for a, b in zip(jax.tree_util.tree_leaves(together), want):
+        np.testing.assert_array_equal(np.asarray(a), b)
 
 # -- LocalScheduler edge cases (the invariants cross-instance migration
 # -- relies on): exhausted block pool, rollback-then-requeue consistency
@@ -275,3 +287,22 @@ def test_sampling_per_row_positions_match_scalar():
     for i, pos in enumerate([5, 9, 2]):
         solo = sample(logits[i:i + 1], p, step=pos)
         assert batched[i] == solo[0]
+
+
+def test_compile_cache_dir_honours_env(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the graph cache uses it and
+    sets no directory in code; without it, one fixed directory inside the
+    checkout (listed in .gitignore), never one derived from a workdir."""
+    import os
+    from repro.core import graph_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    assert graph_cache.GraphCache().persist_dir == str(tmp_path / "cc")
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    gc_ = graph_cache.GraphCache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert gc_.persist_dir == os.path.join(root, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == gc_.persist_dir
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
