@@ -315,9 +315,9 @@ def frontend_bench(workdir: str, quick: bool = False,
     for w in workers:
         w.join()
     wall = time.perf_counter() - t0
-    gaps = [inst.engine.host_gap_fraction()
-            for inst in fleet.instances.values()
-            if inst.state.value != "dead"]
+    shares = [inst.engine.telemetry.summary()["host_share"]
+              for inst in fleet.instances.values()
+              if inst.state.value != "dead"]
     asyncio.run_coroutine_threadsafe(fe.stop(), loop).result(timeout=30)
     loop.call_soon_threadsafe(loop.stop)
     th.join(timeout=10)
@@ -333,7 +333,7 @@ def frontend_bench(workdir: str, quick: bool = False,
         "p99_latency_s": p99,
         "slo_s": slo_s,
         "p99_within_slo": bool(p99 <= slo_s),
-        "host_gap_fraction": round(float(np.mean(gaps)), 4),
+        "host_share": round(float(np.mean(shares)), 4),
     }
     assert out["p99_within_slo"], out
     return out
@@ -472,7 +472,7 @@ def print_table(out: Dict) -> None:
         print(f"  p50 {fr['p50_latency_s'] * 1e3:.0f}ms  "
               f"p99 {fr['p99_latency_s'] * 1e3:.0f}ms  "
               f"(SLO {fr['slo_s']:.0f}s: {ok})  "
-              f"host gap {fr['host_gap_fraction'] * 100:.1f}%")
+              f"host share {fr['host_share'] * 100:.1f}%")
 
 
 if __name__ == "__main__":

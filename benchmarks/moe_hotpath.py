@@ -293,10 +293,10 @@ def run_spec_decode(quick: bool = False, iters: int = 5) -> List[Dict]:
 def run_engine_overlap(quick: bool = False, iters: int = 5) -> List[Dict]:
     """Engine rows: lockstep vs the async pipelined engine on the same
     workload.  ``metric_us`` is wall-clock time per emitted token
-    (engine TPOT); each row also records ``host_gap_fraction`` — the
-    share of executor wall time the device sat idle waiting on host
-    planning/sampling/readback — which is the number the overlap
-    pipeline exists to reduce.  The two modes' token streams are
+    (engine TPOT); each row also records ``host_share`` — the share of
+    the engine steps' wall time the host spent not waiting on a logits
+    readback (``telemetry.summary()``) — which the overlap pipeline
+    exists to reduce.  The two modes' token streams are
     asserted identical (overlap is a schedule change, not a sampling
     change).
 
@@ -338,9 +338,7 @@ def run_engine_overlap(quick: bool = False, iters: int = 5) -> List[Dict]:
                     [list(r.output_tokens) for r in reqs])
 
         serve()                      # warmup: compiles off the clock
-        eng.perf["wall_s"] = 0.0     # gap measured on warm serves only
-        for ex in eng.dp_executors:
-            ex.perf["device_busy_s"] = 0.0
+        warm = eng.telemetry.n_steps  # share measured on warm serves only
         serves = 1 if quick else min(iters, 3)
         best_us = float("inf")
         toks = None
@@ -351,7 +349,8 @@ def run_engine_overlap(quick: bool = False, iters: int = 5) -> List[Dict]:
         row = {
             "name": f"engine_{mode}", "kind": "engine",
             "T": len(prompts), "metric_us": best_us,
-            "host_gap_fraction": round(eng.host_gap_fraction(), 4),
+            "host_share": round(
+                eng.telemetry.summary(since=warm)["host_share"], 4),
             "serves": serves,
             "backend": jax.default_backend(),
             "use_pallas": jax.default_backend() not in ("cpu",),
@@ -409,14 +408,14 @@ def print_table(rows: List[Dict]) -> None:
     if engine:
         print(f"\n# Engine: lockstep vs async pipelined "
               f"(token-identical, backend={backend})")
-        print(f"{'mode':18s} {'us/token':>10s} {'host gap':>9s} "
+        print(f"{'mode':18s} {'us/token':>10s} {'host':>9s} "
               f"{'planned ahead':>14s} {'replans':>8s}")
         for r in engine:
             ov = r.get("overlap", {})
             pa = str(ov.get("planned_ahead", "—"))
             rp = str(ov.get("replans", "—"))
             print(f"{r['name']:18s} {r['metric_us']:10.0f} "
-                  f"{r['host_gap_fraction']:8.1%} {pa:>14s} {rp:>8s}")
+                  f"{r['host_share']:8.1%} {pa:>14s} {rp:>8s}")
 
 
 def save_json(rows: List[Dict], path: str = BENCH_PATH, *,

@@ -61,14 +61,15 @@ def main(argv=None) -> int:
                                  f"hist={hist}"))
             elif r.get("kind") == "engine":
                 # host/device overlap surface: wall-clock TPOT plus the
-                # fraction of wall time the device idled on the host
+                # share of step time the host spent not waiting on a
+                # readback
                 ov = r.get("overlap", {})
                 extra = (f",planned_ahead={ov['planned_ahead']}"
                          f",replans={ov['replans']}" if ov else "")
                 csv_rows.append((f"moe_hotpath_{r['name']}",
                                  f"{r['metric_us']:.0f}",
-                                 f"host_gap_fraction="
-                                 f"{r['host_gap_fraction']:.4f}{extra}"))
+                                 f"host_share="
+                                 f"{r['host_share']:.4f}{extra}"))
             elif "mega_us" in r:
                 csv_rows.append((f"moe_hotpath_{r['name']}_mega",
                                  f"{r['mega_us']:.0f}",
@@ -147,7 +148,7 @@ def main(argv=None) -> int:
                 f"{1e6 / max(fr['req_per_s'], 1e-9):.0f}",
                 f"req_per_s={fr['req_per_s']:.3f},"
                 f"p99_s={fr['p99_latency_s']:.3f},"
-                f"host_gap_fraction={fr['host_gap_fraction']:.4f}"))
+                f"host_share={fr['host_share']:.4f}"))
 
     if want("fleet_campaign"):
         from benchmarks import fleet_campaign

@@ -15,7 +15,9 @@ mirroring the paper's Figure 5 categories:
   minute case, scaled down to our model sizes).
 
 Every compile is timed and the (read_cache_s, compile_s, source) triple
-is what benchmarks/recovery_time.py reports.
+is what benchmarks/recovery_time.py reports; ``timings`` lists the
+compiles (misses and precompiles) and ``hits`` counts the lookups that
+found a ready executable (both served on ``/instances``).
 
 The on-disk tier lives where ``JAX_COMPILATION_CACHE_DIR`` says when it
 is set (JAX reads it itself; nothing here sets another directory), and
@@ -62,6 +64,7 @@ class GraphCache:
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         self._exec: Dict[Tuple, Any] = {}
         self.timings: list[CompileTiming] = []
+        self.hits = 0
 
     def __contains__(self, key) -> bool:
         return key in self._exec
@@ -90,9 +93,9 @@ class GraphCache:
         """Recovery-time lookup: precompiled hit is ~free; otherwise a real
         (possibly persistent-cache-served) compile happens and is timed."""
         if key in self._exec:
-            tm = CompileTiming("precompiled", 0.0, 0.0, key)
-            self.timings.append(tm)
-            return self._exec[key], tm
+            self.hits += 1
+            return self._exec[key], CompileTiming("precompiled", 0.0, 0.0,
+                                                  key)
         t0 = time.perf_counter()
         lowered = jax.jit(fn, donate_argnums=donate_argnums).lower(*arg_shapes)
         t1 = time.perf_counter()

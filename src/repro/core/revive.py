@@ -14,12 +14,12 @@ On an actionable fault:
      compilation — precompiled failure-scenario executables when
      available (§3.6) — and inference resumes.
 
-Every stage is wall-clock timed into the paper's Table-1 categories so
+Every stage is a span of the engine's telemetry, ``repro.revive.<key>``,
+whose seconds go into the paper's Table-1 categories so
 benchmarks/recovery_time.py can reproduce Figure 5.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -28,6 +28,7 @@ from repro.core.migration import plan_migration, prepare_for_migration
 from repro.core.weights import (MoERecoveryKind, MoERecoveryPlan,
                                 plan_moe_recovery)
 from repro.serving.request import RequestState
+from repro.serving.telemetry import REVIVE
 
 CATEGORIES = ("engine", "executor_processes", "distributed_groups", "xccl",
               "role_switch", "generator", "read_cache", "compile", "other")
@@ -72,26 +73,21 @@ class RecoveryReport:
                 f"compile={self.compile_source} :: {cats}")
 
 
-class _T:
-    def __init__(self, report: RecoveryReport, key: str):
-        self.r, self.k = report, key
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-
-    def __exit__(self, *exc):
-        self.r.timings[self.k] = self.r.timings.get(self.k, 0.0) + (
-            time.perf_counter() - self.t0)
-
-
 class RecoveryManager:
     def __init__(self, engine):
         self.engine = engine
         self.policy = engine.ecfg.policy
 
+    def _stage(self, report: RecoveryReport, key: str):
+        return self.engine.telemetry.stage(REVIVE, report.timings, key)
+
     # -- pipeline ----------------------------------------------------------------
 
     def recover(self, event: FaultEvent) -> RecoveryReport:
+        with self.engine.telemetry.span(REVIVE):
+            return self._recover(event)
+
+    def _recover(self, event: FaultEvent) -> RecoveryReport:
         eng = self.engine
         report = RecoveryReport(event=event, scenario="?",
                                 mode=eng.ecfg.mode)
@@ -107,7 +103,7 @@ class RecoveryManager:
                            "collocated"))
 
         # ① isolate: pause inference, terminate only the failed process
-        with _T(report, "other"):
+        with self._stage(report, "other"):
             device.alive = False
             failed_dp = None
             failed_moe = None
@@ -127,7 +123,7 @@ class RecoveryManager:
         # ③ block-table + pool recovery on all surviving executors —
         # BEFORE any migration, so streamed KV blocks land on targets
         # whose tables and pools already agree (rollback-then-migrate)
-        with _T(report, "other"):
+        with self._stage(report, "other"):
             undone = 0
             for ex in eng.dp_executors:
                 if ex.alive and ex.cache is not None:
@@ -139,7 +135,7 @@ class RecoveryManager:
         # device memory is gone, so its KV cannot stream: token-replay
         # re-prefill is the (verified) fallback here.
         if failed_dp is not None and is_attn:
-            with _T(report, "other"):
+            with self._stage(report, "other"):
                 reqs = failed_dp.scheduler.drain()
                 report.migrated, _ = self._migrate(reqs, exclude=failed_dp)
                 report.actions.append(
@@ -161,31 +157,29 @@ class RecoveryManager:
             report.scenario = "attn"
 
         # ⑤ recreate communications with compacted ranks
-        with _T(report, "xccl"):
+        with self._stage(report, "xccl"):
             rec = eng.domain.rebuild(role_switch_physical=role_switch_pid)
             report.actions.append(
                 f"comm domain v{rec['version']} rebuilt; rank changes: "
                 f"{rec['rank_changes']}")
-        with _T(report, "distributed_groups"):
+        with self._stage(report, "distributed_groups"):
             # torch-group analogue: world group intact, subgroups reassigned
             eng.world_group = [ex.physical_id for ex in eng.dp_executors
                                if ex.alive] + \
                               [m.physical_id for m in eng.moe_executors
                                if m.device_alive]
 
-        # ⑥ cached graph compilation for the new domain version
-        with _T(report, "read_cache"):
-            pass  # timed inside get_or_compile; split below
+        # ⑥ cached graph compilation for the new domain version; the
+        # span's seconds split into read_cache, compile and other
         key_hit_before = ("decode", eng.domain.version, None) in eng.graph_cache
-        t0 = time.perf_counter()
-        eng.get_compiled("decode")
-        tm = eng.graph_cache.timings[-1]
+        with eng.telemetry.span(REVIVE + ".graph") as sp:
+            _, tm = eng.compiled_with_timing("decode")
         report.compile_source = tm.source
         report.timings["read_cache"] = report.timings.get(
             "read_cache", 0.0) + tm.read_cache_s
         report.timings["compile"] = report.timings.get(
             "compile", 0.0) + tm.compile_s
-        leftover = (time.perf_counter() - t0) - tm.read_cache_s - tm.compile_s
+        leftover = sp.seconds - tm.read_cache_s - tm.compile_s
         report.timings["other"] = report.timings.get("other", 0.0) + max(
             leftover, 0.0)
         report.actions.append(
@@ -240,7 +234,7 @@ class RecoveryManager:
                           else failed_dp.ep_rank)
         if failed_ep_rank is None:
             return None
-        with _T(report, "other"):
+        with self._stage(report, "other"):
             affected = emap.fail_rank(failed_ep_rank)
             report.actions.append(
                 f"EP rank {failed_ep_rank} lost (experts {affected[:8]}"
@@ -249,14 +243,14 @@ class RecoveryManager:
         plan = plan_moe_recovery(emap, self.policy, donor)
 
         if plan.kind is MoERecoveryKind.REDUNDANT_EXPERTS:
-            with _T(report, "other"):
+            with self._stage(report, "other"):
                 eng.runtime = emap.runtime()
                 eng.reassemble_params()
                 report.actions.append(
                     "dropped dead replicas from logical-to-physical map")
 
         elif plan.kind is MoERecoveryKind.MISSING_EXPERTS:
-            with _T(report, "other"):
+            with self._stage(report, "other"):
                 emap.mask_experts(plan.lost_logicals)
                 eng.runtime = emap.runtime()
                 eng.reassemble_params()
@@ -270,7 +264,7 @@ class RecoveryManager:
             # §4.3 combined mode: mask the lost experts NOW (serve with the
             # incomplete expert set — downtime stays at missing-experts
             # level) and restore full weight integrity in the background.
-            with _T(report, "other"):
+            with self._stage(report, "other"):
                 emap.mask_experts(plan.lost_logicals)
                 eng.runtime = emap.runtime()
                 eng.reassemble_params()
@@ -281,7 +275,7 @@ class RecoveryManager:
 
         elif plan.kind is MoERecoveryKind.ROLE_SWITCH:
             donor_ex = eng.dp_executors[plan.donor_rank]
-            with _T(report, "role_switch"):
+            with self._stage(report, "role_switch"):
                 # migrate the donor's residents — the donor device is
                 # healthy, so their KV blocks *stream* to the targets
                 # instead of re-prefilling — then drop its attention duty
@@ -293,7 +287,7 @@ class RecoveryManager:
                     f"role switch: dp{plan.donor_rank} -> moe ep-rank "
                     f"{failed_ep_rank}; migrated {n} of its sequences "
                     f"({n_streamed} KV-streamed)")
-            with _T(report, "generator"):
+            with self._stage(report, "generator"):
                 # the lost experts' only copies are gone: load from disk
                 from repro.serving.weights_util import (
                     load_expert_shard_from_checkpoint)
@@ -322,7 +316,7 @@ class RecoveryManager:
             recovered = (plan.kind is MoERecoveryKind.ROLE_SWITCH
                          and not plan.background)
             if not recovered:
-                with _T(report, "other"):
+                with self._stage(report, "other"):
                     group = failed_ep_rank % eng.dense_groups.num_groups
                     if eng.dense_groups.alive[group]:
                         eng.dense_groups.fail_shard(group)
@@ -348,22 +342,21 @@ class RecoveryManager:
                 failed_ep_rank = r
                 break
         assert failed_ep_rank is not None
-        t0 = time.perf_counter()
-        reqs = donor_ex.drop_attention_state(collect_kv=True)
-        self._migrate(reqs, exclude=donor_ex)
-        donor_ex.ep_rank = failed_ep_rank
-        timings["role_switch"] = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        from repro.serving.weights_util import (
-            load_expert_shard_from_checkpoint)
-        shard = load_expert_shard_from_checkpoint(
-            eng.ckpt_path, eng.shards[failed_ep_rank], failed_ep_rank,
-            eng.ep_size, workdir=eng.ecfg.workdir)
-        donor_ex.shard = shard
-        restored = emap.install_rank(failed_ep_rank)
-        eng.runtime = emap.runtime()
-        eng.reassemble_params()
-        timings["generator"] = time.perf_counter() - t0
+        tel = eng.telemetry
+        with tel.stage(REVIVE + ".background", timings, "role_switch"):
+            reqs = donor_ex.drop_attention_state(collect_kv=True)
+            self._migrate(reqs, exclude=donor_ex)
+            donor_ex.ep_rank = failed_ep_rank
+        with tel.stage(REVIVE + ".background", timings, "generator"):
+            from repro.serving.weights_util import (
+                load_expert_shard_from_checkpoint)
+            shard = load_expert_shard_from_checkpoint(
+                eng.ckpt_path, eng.shards[failed_ep_rank], failed_ep_rank,
+                eng.ep_size, workdir=eng.ecfg.workdir)
+            donor_ex.shard = shard
+            restored = emap.install_rank(failed_ep_rank)
+            eng.runtime = emap.runtime()
+            eng.reassemble_params()
         timings["restored_experts"] = float(len(restored))
         return timings
 

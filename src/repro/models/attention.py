@@ -254,23 +254,25 @@ def gqa_decode_qkv(p, cfg: ModelConfig, x, page, *, use_rope=True):
     Dh = cfg.resolved_head_dim()
     H, Hkv = cfg.num_heads, cfg.num_kv_heads
     pos = page["seq_lens"] - 1
-    q = (x @ p["wq"]).reshape(B, H, Dh)
-    k = (x @ p["wk"]).reshape(B, Hkv, Dh)
-    v = (x @ p["wv"]).reshape(B, Hkv, Dh)
-    if use_rope:
-        sin, cos = rope_sincos(pos, Dh, cfg.rope_theta)
-        q = apply_rope(q, sin[:, None, :], cos[:, None, :])
-        k = apply_rope(k, sin[:, None, :], cos[:, None, :])
+    with jax.named_scope("attn.qkv"):
+        q = (x @ p["wq"]).reshape(B, H, Dh)
+        k = (x @ p["wk"]).reshape(B, Hkv, Dh)
+        v = (x @ p["wv"]).reshape(B, Hkv, Dh)
+        if use_rope:
+            sin, cos = rope_sincos(pos, Dh, cfg.rope_theta)
+            q = apply_rope(q, sin[:, None, :], cos[:, None, :])
+            k = apply_rope(k, sin[:, None, :], cos[:, None, :])
     return q, k, v
 
 
 def gqa_write_token(pools, page, k, v):
     """Scatter one incoming token's K/V into its (block, offset) rows
     (idle batch slots hit the trash block)."""
-    k_pool = pools["k"].at[page["write_bid"], page["write_off"]].set(
-        k.astype(pools["k"].dtype))
-    v_pool = pools["v"].at[page["write_bid"], page["write_off"]].set(
-        v.astype(pools["v"].dtype))
+    with jax.named_scope("attn.kv_write"):
+        k_pool = pools["k"].at[page["write_bid"], page["write_off"]].set(
+            k.astype(pools["k"].dtype))
+        v_pool = pools["v"].at[page["write_bid"], page["write_off"]].set(
+            v.astype(pools["v"].dtype))
     return {"k": k_pool, "v": v_pool}
 
 
@@ -290,11 +292,13 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, pools, page, *,
     H = cfg.num_heads
     q, k, v = gqa_decode_qkv(p, cfg, x, page, use_rope=use_rope)
     new_pools = gqa_write_token(pools, page, k, v)
-    out = ops.paged_attention(q, new_pools["k"], new_pools["v"],
-                              page["tables"], page["seq_lens"],
-                              window_starts(cfg, page["seq_lens"]),
-                              use_pallas=use_pallas)
-    y = out.reshape(B, H * Dh).astype(x.dtype) @ p["wo"]
+    with jax.named_scope("attn.paged"):
+        out = ops.paged_attention(q, new_pools["k"], new_pools["v"],
+                                  page["tables"], page["seq_lens"],
+                                  window_starts(cfg, page["seq_lens"]),
+                                  use_pallas=use_pallas)
+    with jax.named_scope("attn.out"):
+        y = out.reshape(B, H * Dh).astype(x.dtype) @ p["wo"]
     return y, new_pools
 
 
@@ -320,20 +324,23 @@ def mla_decode_q_token(p, cfg: ModelConfig, x, page):
     dn, dr = m.qk_nope_head_dim, m.qk_rope_head_dim
     R = m.kv_lora_rank
     pos = page["seq_lens"] - 1
-    q_nope, q_rope, c_kv, k_rope, sin, cos = _mla_qkr(p, cfg, x, pos)
-    q_rope = apply_rope(q_rope, sin[:, None, :], cos[:, None, :])  # (B,H,dr)
-    k_rope = apply_rope(k_rope, sin, cos)                          # (B,dr)
-    q_lat = jnp.einsum("bhd,hdr->bhr", q_nope, p["wuk"])           # (B,H,R)
-    token = jnp.concatenate([c_kv, k_rope], axis=-1)               # (B,R+dr)
-    q_eff = jnp.concatenate([q_lat, q_rope], axis=-1) * (
-        math.sqrt(R + dr) / math.sqrt(dn + dr))
+    with jax.named_scope("attn.qkv"):
+        q_nope, q_rope, c_kv, k_rope, sin, cos = _mla_qkr(p, cfg, x, pos)
+        q_rope = apply_rope(q_rope, sin[:, None, :],
+                            cos[:, None, :])                       # (B,H,dr)
+        k_rope = apply_rope(k_rope, sin, cos)                      # (B,dr)
+        q_lat = jnp.einsum("bhd,hdr->bhr", q_nope, p["wuk"])       # (B,H,R)
+        token = jnp.concatenate([c_kv, k_rope], axis=-1)           # (B,R+dr)
+        q_eff = jnp.concatenate([q_lat, q_rope], axis=-1) * (
+            math.sqrt(R + dr) / math.sqrt(dn + dr))
     return q_eff, token
 
 
 def mla_write_token(pools, page, token):
     """Scatter one incoming token's fused latent row into its block."""
-    pool = pools["ckr"].at[page["write_bid"], page["write_off"], 0].set(
-        token.astype(pools["ckr"].dtype))
+    with jax.named_scope("attn.kv_write"):
+        pool = pools["ckr"].at[page["write_bid"], page["write_off"], 0].set(
+            token.astype(pools["ckr"].dtype))
     return {"ckr": pool}
 
 
@@ -370,13 +377,16 @@ def mla_decode_paged(p, cfg: ModelConfig, x, pools, page, *,
     q_eff, token = mla_decode_q_token(p, cfg, x, page)
     new_pools = mla_write_token(pools, page, token)
     pool = new_pools["ckr"]
-    out = ops.paged_attention(q_eff.astype(pool.dtype), pool, pool,
-                              page["tables"], page["seq_lens"],
-                              window_starts(cfg, page["seq_lens"]),
-                              use_pallas=use_pallas)
-    o_lat = out[..., :R]                                           # (B,H,R)
-    o = jnp.einsum("bhr,hrv->bhv", o_lat.astype(x.dtype), p["wuv"])
-    return o.reshape(B, H * dv) @ p["wo"], new_pools
+    with jax.named_scope("attn.paged"):
+        out = ops.paged_attention(q_eff.astype(pool.dtype), pool, pool,
+                                  page["tables"], page["seq_lens"],
+                                  window_starts(cfg, page["seq_lens"]),
+                                  use_pallas=use_pallas)
+    with jax.named_scope("attn.out"):
+        o_lat = out[..., :R]                                       # (B,H,R)
+        o = jnp.einsum("bhr,hrv->bhv", o_lat.astype(x.dtype), p["wuv"])
+        y = o.reshape(B, H * dv) @ p["wo"]
+    return y, new_pools
 
 
 def gqa_cross_decode(p, cfg: ModelConfig, x, ck, cv, kv_valid):

@@ -577,7 +577,8 @@ class Model:
         """
         cfg = self.cfg
         runtime = runtime if runtime is not None else self.default_runtime()
-        x = params["embed"][token]                       # (B, D)
+        with jax.named_scope("embed"):
+            x = params["embed"][token]                   # (B, D)
         B = x.shape[0]
         cap = self._cap(B) if cfg.moe else 0
         new_cache = dict(cache)
@@ -596,8 +597,9 @@ class Model:
             x, entries, _ = self._run_stack(params[name], x, body, n,
                                             cache=cache[name])
             new_cache[name] = entries
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = x @ params["lm_head"]
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            logits = x @ params["lm_head"]
         return logits, new_cache
 
     def _block_decode_paged(self, p, x, csl, page, runtime, cap, *,
@@ -623,7 +625,8 @@ class Model:
         x = x + out
         if ffn_kind in ("dense", "dense_first"):
             h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-            x = x + F.ffn_apply(p["ffn"], h2, cfg.activation)
+            with jax.named_scope("ffn"):
+                x = x + F.ffn_apply(p["ffn"], h2, cfg.activation)
         elif ffn_kind == "moe":
             h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
             y, aux = self._moe(p["moe"], h2, runtime, cap)
@@ -661,18 +664,19 @@ class Model:
             starts = jnp.zeros_like(page["seq_lens"])
         moe_p = p["moe"]
         shared = moe_p.get("shared")
-        y, _ = ops.decode_megastep(
-            q, k_pool, v_pool, page["tables"], page["seq_lens"], starts,
-            x, w_post, p["ln2"], moe_p["router"],
-            runtime.logical_to_physical, runtime.replica_count,
-            runtime.expert_mask, moe_p["gate"], moe_p["up"],
-            moe_p["down"], jnp.int32(0),
-            shared["w_gate"] if shared else None,
-            shared["w_up"] if shared else None,
-            shared["w_down"] if shared else None,
-            top_k=cfg.moe.top_k, cap=cap,
-            e_local=MoE.physical_experts(cfg.moe), eps=cfg.norm_eps,
-            use_pallas=use_pallas)
+        with jax.named_scope("megastep"):
+            y, _ = ops.decode_megastep(
+                q, k_pool, v_pool, page["tables"], page["seq_lens"], starts,
+                x, w_post, p["ln2"], moe_p["router"],
+                runtime.logical_to_physical, runtime.replica_count,
+                runtime.expert_mask, moe_p["gate"], moe_p["up"],
+                moe_p["down"], jnp.int32(0),
+                shared["w_gate"] if shared else None,
+                shared["w_up"] if shared else None,
+                shared["w_down"] if shared else None,
+                top_k=cfg.moe.top_k, cap=cap,
+                e_local=MoE.physical_experts(cfg.moe), eps=cfg.norm_eps,
+                use_pallas=use_pallas)
         return y, entry, 0.0
 
     def _period_decode_paged(self, p, x, csl, page, runtime, cap):

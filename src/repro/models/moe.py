@@ -246,16 +246,19 @@ def moe_apply_local(p, cfg: ModelConfig, x_flat, runtime: MoERuntime, *,
     """
     moe = cfg.moe
     e_local = e_local if e_local is not None else physical_experts(moe)
-    weights, sel, aux = route(p["router"], x_flat, runtime, moe)
-    phys, alive = select_replicas(sel, runtime)
-    y = dispatch_fn(cfg)(
-        x_flat, weights, phys, alive, p["gate"], p["up"], p["down"],
-        cap=cap, expert_offset=expert_offset, e_local=e_local)
+    with jax.named_scope("moe.router"):
+        weights, sel, aux = route(p["router"], x_flat, runtime, moe)
+        phys, alive = select_replicas(sel, runtime)
+    with jax.named_scope("moe.experts"):
+        y = dispatch_fn(cfg)(
+            x_flat, weights, phys, alive, p["gate"], p["up"], p["down"],
+            cap=cap, expert_offset=expert_offset, e_local=e_local)
     return y, aux
 
 
 def shared_expert_apply(p, cfg: ModelConfig, x):
     if cfg.moe and cfg.moe.num_shared_experts and "shared" in p:
         from repro.models.ffn import ffn_apply
-        return ffn_apply(p["shared"], x, "swiglu")
+        with jax.named_scope("moe.shared"):
+            return ffn_apply(p["shared"], x, "swiglu")
     return 0.0

@@ -35,22 +35,11 @@ from repro.models.model import Model
 from repro.serving.executor import DPExecutor, MoEExecutor, next_bucket
 from repro.serving.request import Request, RequestState
 from repro.serving.sampling import SamplingParams
+from repro.serving.telemetry import (BUILD, CONTROL, HEALTH, Telemetry,
+                                     register)
 from repro.serving.weights_util import (bank_programs, split_experts,
                                         update_rank_slice)
 from repro.training.checkpoint import restore_like, save_checkpoint
-
-
-class _Timer:
-    def __init__(self, sink: Dict[str, float], key: str):
-        self.sink, self.key = sink, key
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self.sink[self.key] = self.sink.get(self.key, 0.0) + (
-            time.perf_counter() - self.t0)
 
 
 def _specs(tree):
@@ -320,18 +309,26 @@ class InferenceEngine:
         # slowdown) instead of the wall clock, so chaos campaigns are a
         # pure function of their seed
         self.virtual_step_s: Optional[float] = None
-        # wall-clock spent inside executor step calls (summed across
-        # ranks, both lockstep and overlap paths) — the denominator of
-        # ``host_gap_fraction``; the numerator lives on the executors
-        self.perf: Dict[str, float] = {"wall_s": 0.0}
+        # spans of every step, build and revive; registered once, so a
+        # rebuild (revive, restart) keeps recording into the same one
+        self.telemetry = Telemetry()
+        register(self.telemetry)
         self._build(first_time=True)
 
     # -- construction / reinitialization ---------------------------------------
 
     def _build(self, first_time: bool) -> Dict[str, float]:
+        with self.telemetry.span(BUILD):
+            return self._build_stages(first_time)
+
+    def _build_stages(self, first_time: bool) -> Dict[str, float]:
         ec = self.ecfg
         t: Dict[str, float] = {}
-        with _Timer(t, "engine"):
+
+        def stage(key):
+            return self.telemetry.stage(BUILD, t, key)
+
+        with stage("engine"):
             # paper baseline is a *cached* reinit: the compile cache lives
             # on disk (Dynamo/IR cache analogue = XLA persistent cache)
             self.graph_cache = getattr(self, "graph_cache", None) or \
@@ -347,7 +344,7 @@ class InferenceEngine:
             os.makedirs(ec.workdir, exist_ok=True)
             self.ckpt_path = os.path.join(ec.workdir, "weights.npz")
 
-        with _Timer(t, "generator"):
+        with stage("generator"):
             # model instantiation + weight loading + KV warmup
             # the device holds the weights once: ``self.params`` is the
             # served tree, expert bank included (every rank alive)
@@ -379,7 +376,7 @@ class InferenceEngine:
                 self.bank_shards = []
                 self.dense_groups = None
 
-        with _Timer(t, "executor_processes"):
+        with stage("executor_processes"):
             self.dp_executors: List[DPExecutor] = []
             for i in range(ec.num_dp):
                 shard = None
@@ -398,12 +395,12 @@ class InferenceEngine:
             for ex in self.dp_executors + self.moe_executors:
                 self.monitor.register(ex.physical_id, self.step_no)
 
-        with _Timer(t, "distributed_groups"):
+        with stage("distributed_groups"):
             # torch.distributed analogue: default world group + subgroups
             self.world_group = [ex.physical_id for ex in
                                 self.dp_executors + self.moe_executors]
 
-        with _Timer(t, "xccl"):
+        with stage("xccl"):
             self.domain = CommDomain(
                 ec.num_dp,
                 ec.num_moe if ec.mode == "disaggregated" else 0,
@@ -413,13 +410,14 @@ class InferenceEngine:
             self.domain.rebuild()
 
         # initial graph compilation (Fig. 1 "Read Cache"/"Compile")
-        self._compile_initial(t)
+        with self.telemetry.span(BUILD + ".graph"):
+            self._compile_initial(t)
 
         if first_time and ec.precompile_failure_scenarios:
-            with _Timer(t, "precompile_failure_scenarios"):
+            with stage("precompile_failure_scenarios"):
                 self._precompile_failure_graphs()
 
-        with _Timer(t, "other"):
+        with stage("other"):
             from repro.core.revive import RecoveryManager
             self.recovery = RecoveryManager(self)
         self.init_timings = t
@@ -442,7 +440,8 @@ class InferenceEngine:
                           else ec.max_batch + ec.prefill_chunk),
             prefix_cache=ec.prefix_cache,
             pool_undo=ec.pool_undo,
-            spec_window=ec.spec_window)
+            spec_window=ec.spec_window,
+            telemetry=self.telemetry)
 
     @property
     def _next_version(self) -> int:
@@ -568,13 +567,18 @@ class InferenceEngine:
     # -- compiled-fn access ------------------------------------------------------
 
     def get_compiled(self, phase: str, bucket: Optional[int] = None):
+        return self.compiled_with_timing(phase, bucket)[0]
+
+    def compiled_with_timing(self, phase: str,
+                             bucket: Optional[int] = None):
+        """The executable of ``phase`` for the current domain version, and
+        the :class:`~repro.core.graph_cache.CompileTiming` of getting it."""
         # the install scatter has no collectives: its graph is domain-
         # version independent and survives every comm rebuild
         v = 0 if phase == "install" else self.domain.version
         key = (phase, v, bucket if phase in ("prefill", "install") else None)
         if key in self.graph_cache:
-            fn, _ = self.graph_cache.get_or_compile(key, None, None)
-            return fn
+            return self.graph_cache.get_or_compile(key, None, None)
         if phase == "decode":
             fn = _decode_closure(self.model, v)
         elif phase == "chunk":
@@ -583,10 +587,9 @@ class InferenceEngine:
             fn = _install_closure(self.paged_axes, bucket)
         else:
             fn = _prefill_closure(self.model, v, self.ecfg.max_seq)
-        compiled, _ = self.graph_cache.get_or_compile(
+        return self.graph_cache.get_or_compile(
             key, fn, self._arg_specs(phase, bucket),
             donate_argnums=self._donate(phase))
-        return compiled
 
     # -- request API ----------------------------------------------------------------
 
@@ -802,20 +805,68 @@ class InferenceEngine:
     # -- main loop --------------------------------------------------------------------
 
     def step(self) -> List[Request]:
-        if self._overlap_active:
-            return self._step_overlap()
         self.step_no += 1
-        # finish deferred role switches in the background (§4.3): service
-        # already resumed; these timings are not downtime
+        with self.telemetry.step(self.step_no):
+            if self._overlap_active:
+                return self._step_overlap()
+            return self._step_lockstep()
+
+    def _pre_step(self) -> None:
+        """Finish deferred role switches in the background (§4.3: service
+        already resumed; these timings are not downtime), then fire the
+        faults due before the step."""
         while self.pending_switches:
             plan = self.pending_switches.pop(0)
             self.background_reports.append(
                 self.recovery.complete_background_switch(plan))
         self.injector.pre_step_faults(self.step_no)
-        for ev in self.poller.poll():
-            self._handle(ev)
-        for ev in self.monitor.check(self.step_no):
-            self._handle(ev)
+
+    def _fail_mid_step(self, executors) -> bool:
+        """Mid-step faults fire while the collective step is in flight;
+        True when one did (its annotation is then pending)."""
+        hit = False
+        for ex in executors:
+            try:
+                self.injector.maybe_fail_mid_step(self.step_no,
+                                                  ex.physical_id)
+            except SimulatedDeviceFailure:
+                ex.fail_device()
+                hit = True
+        return hit
+
+    def _step_health(self, samples) -> List[Any]:
+        """Record each ``(executor, wall seconds, compiled)`` sample of
+        the step and run the straggler check (slowdown detection, §6
+        future work); returns the straggler events."""
+        for ex, wall_s, compiled in samples:
+            if self.virtual_step_s is not None:
+                base = self.virtual_step_s
+            elif compiled:
+                continue     # a step that compiled says nothing of speed
+            else:
+                base = wall_s
+            self.straggler.record(ex.physical_id,
+                                  base + ex.simulated_slowdown_s)
+        # soft signal: suspicion that has not yet hardened into an L4
+        # fault, surfaced via health() for the fleet arbiter to act on
+        self.soft_signals = self.straggler.suspects()
+        return list(self.straggler.check())
+
+    def _beat(self) -> None:
+        for ex in self.dp_executors + self.moe_executors:
+            alive = (ex.device_alive if isinstance(ex, MoEExecutor)
+                     else ex.alive)
+            if alive:
+                self.monitor.beat(ex.physical_id, self.step_no)
+
+    def _step_lockstep(self) -> List[Request]:
+        tel = self.telemetry
+        with tel.span(CONTROL, step=self.step_no):
+            self._pre_step()
+            for ev in self.poller.poll():
+                self._handle(ev)
+            for ev in self.monitor.check(self.step_no):
+                self._handle(ev)
 
         active = [ex for ex in self.dp_executors
                   if ex.alive and ex.cache is not None
@@ -823,54 +874,31 @@ class InferenceEngine:
         for ex in active:
             ex.plan()
 
-        # mid-step faults fire while the collective step is in flight
-        hit = False
-        for ex in active + [m for m in self.moe_executors if m.device_alive]:
-            try:
-                self.injector.maybe_fail_mid_step(self.step_no,
-                                                  ex.physical_id)
-            except SimulatedDeviceFailure:
-                ex.fail_device()
-                if ex.ep_rank is not None and self.expert_map is not None:
-                    pass  # handled by recovery via the annotation
-                hit = True
+        with tel.span(CONTROL, step=self.step_no):
+            hit = self._fail_mid_step(
+                active + [m for m in self.moe_executors if m.device_alive])
+            if hit:
+                # global stop: the step aborts with uncommitted logs
+                # everywhere; detection fires on the annotation just
+                # recorded
+                for ev in self.poller.poll():
+                    self._handle(ev)
         if hit:
-            # global stop: the step aborts with uncommitted logs everywhere;
-            # detection fires on the annotation we just recorded
-            for ev in self.poller.poll():
-                self._handle(ev)
             return []
 
         finished: List[Request] = []
         ctx = _Ctx(self)
-        def real_compiles():
-            return sum(1 for t in self.graph_cache.timings
-                       if t.compile_s > 0.01)
-
+        samples = []
         for ex in active:
-            t0 = time.perf_counter()
-            n_compiles = real_compiles()
+            t0, n0 = time.perf_counter(), tel.step_compiles
             finished.extend(ex.compute(ctx, self.step_no))
             ex.commit()
-            self.perf["wall_s"] += time.perf_counter() - t0
-            # slowdown detection (§6 future work): per-device step time;
-            # steps that triggered a fresh compile are not samples
-            if real_compiles() == n_compiles:
-                base = (self.virtual_step_s
-                        if self.virtual_step_s is not None
-                        else time.perf_counter() - t0)
-                self.straggler.record(
-                    ex.physical_id, base + ex.simulated_slowdown_s)
-        # soft signal: suspicion that has not yet hardened into an L4
-        # fault, surfaced via health() for the fleet arbiter to act on
-        self.soft_signals = self.straggler.suspects()
-        for ev in self.straggler.check():
-            self._handle(ev)
-        for ex in self.dp_executors + self.moe_executors:
-            alive = (ex.device_alive if isinstance(ex, MoEExecutor)
-                     else ex.alive)
-            if alive:
-                self.monitor.beat(ex.physical_id, self.step_no)
+            samples.append((ex, time.perf_counter() - t0,
+                            tel.step_compiles != n0))
+        with tel.span(HEALTH, step=self.step_no):
+            for ev in self._step_health(samples):
+                self._handle(ev)
+            self._beat()
         return finished
 
     def _step_overlap(self) -> List[Request]:
@@ -882,73 +910,51 @@ class InferenceEngine:
         readback predates the fault), roll back anything else — so
         recovery, and the migration/replay machinery behind it, sees
         exactly the state lockstep would have committed."""
-        self.step_no += 1
-        while self.pending_switches:
-            plan = self.pending_switches.pop(0)
-            self.background_reports.append(
-                self.recovery.complete_background_switch(plan))
-        self.injector.pre_step_faults(self.step_no)
-        events = list(self.poller.poll()) + list(
-            self.monitor.check(self.step_no))
+        tel = self.telemetry
         finished: List[Request] = []
-        if events:
-            finished.extend(self._quiesce_inflight())
-            for ev in events:
-                self._handle(ev)
-
-        # mid-step faults fire while the previous step's collective is
-        # still in flight — the canonical §3.3 scenario the pipeline
-        # must survive: the already-drained-readback step commits, the
-        # faulted step's partial work rolls back, and replay regenerates
-        # everything after the commit point bit-identically
-        hit = False
-        alive_dp = [ex for ex in self.dp_executors
-                    if ex.alive and ex.cache is not None]
-        for ex in alive_dp + [m for m in self.moe_executors
-                              if m.device_alive]:
-            try:
-                self.injector.maybe_fail_mid_step(self.step_no,
-                                                  ex.physical_id)
-            except SimulatedDeviceFailure:
-                ex.fail_device()
-                hit = True
+        with tel.span(CONTROL, step=self.step_no):
+            self._pre_step()
+            events = list(self.poller.poll()) + list(
+                self.monitor.check(self.step_no))
+            if events:
+                finished.extend(self._quiesce_inflight())
+                for ev in events:
+                    self._handle(ev)
+            # mid-step faults fire while the previous step's collective
+            # is still in flight — the canonical §3.3 scenario the
+            # pipeline must survive: the already-drained-readback step
+            # commits, the faulted step's partial work rolls back, and
+            # replay regenerates everything after the commit point
+            # bit-identically
+            hit = self._fail_mid_step(
+                [ex for ex in self.dp_executors
+                 if ex.alive and ex.cache is not None]
+                + [m for m in self.moe_executors if m.device_alive])
+            if hit:
+                finished.extend(self._quiesce_inflight())
+                for ev in self.poller.poll():
+                    self._handle(ev)
         if hit:
-            finished.extend(self._quiesce_inflight())
-            for ev in self.poller.poll():
-                self._handle(ev)
             return finished
 
         ctx = _Ctx(self)
-        def real_compiles():
-            return sum(1 for t in self.graph_cache.timings
-                       if t.compile_s > 0.01)
-
+        samples = []
         for ex in self.dp_executors:
             if not (ex.alive and ex.cache is not None):
                 continue
             if not (ex.scheduler.num_requests or ex._inflight is not None):
                 continue
-            t0 = time.perf_counter()
-            n_compiles = real_compiles()
+            t0, n0 = time.perf_counter(), tel.step_compiles
             finished.extend(ex.overlap_step(ctx, self.step_no))
-            self.perf["wall_s"] += time.perf_counter() - t0
-            if real_compiles() == n_compiles:
-                base = (self.virtual_step_s
-                        if self.virtual_step_s is not None
-                        else time.perf_counter() - t0)
-                self.straggler.record(
-                    ex.physical_id, base + ex.simulated_slowdown_s)
-        self.soft_signals = self.straggler.suspects()
-        events = list(self.straggler.check())
-        if events:
-            finished.extend(self._quiesce_inflight())
-            for ev in events:
-                self._handle(ev)
-        for ex in self.dp_executors + self.moe_executors:
-            alive = (ex.device_alive if isinstance(ex, MoEExecutor)
-                     else ex.alive)
-            if alive:
-                self.monitor.beat(ex.physical_id, self.step_no)
+            samples.append((ex, time.perf_counter() - t0,
+                            tel.step_compiles != n0))
+        with tel.span(HEALTH, step=self.step_no):
+            events = self._step_health(samples)
+            if events:
+                finished.extend(self._quiesce_inflight())
+                for ev in events:
+                    self._handle(ev)
+            self._beat()
         return finished
 
     def _quiesce_inflight(self) -> List[Request]:
@@ -971,16 +977,6 @@ class InferenceEngine:
             if ex.has_uncommitted():
                 ex.rollback_inflight()
         return finished
-
-    def host_gap_fraction(self) -> float:
-        """Fraction of executor-step wall time the device spent idle
-        waiting on host work (planning, sampling, readback).  The
-        overlap pipeline exists to drive this toward zero."""
-        wall = self.perf["wall_s"]
-        if wall <= 0.0:
-            return 0.0
-        busy = sum(ex.perf["device_busy_s"] for ex in self.dp_executors)
-        return max(0.0, 1.0 - busy / wall)
 
     def overlap_stats(self) -> Dict[str, int]:
         """Aggregated pipeline counters across attention ranks."""

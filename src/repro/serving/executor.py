@@ -41,6 +41,10 @@ from repro.serving.request import Request, RequestState
 from repro.serving.sampling import (SamplingParams, device_predict, sample,
                                     seeded_uniforms, spec_verify)
 from repro.serving.scheduler import ChunkPiece, LocalScheduler, StepPlan
+from repro.serving.telemetry import (COMMIT, LAUNCH, LAUNCH_CHUNK,
+                                     LAUNCH_DECODE, LAUNCH_PREFILL, PLAN,
+                                     READBACK, SAMPLE, UNDO_CAPTURE,
+                                     Telemetry)
 
 
 def next_bucket(n: int, max_seq: int, min_bucket: int = 16) -> int:
@@ -93,7 +97,7 @@ class _Pending:
     host bookkeeping needed to confirm or unwind it."""
     __slots__ = ("plan", "step_no", "chunk_logits", "decode_logits",
                  "pred_chunk", "pred_decode", "points", "det",
-                 "prefill_finished", "t_launch")
+                 "prefill_finished")
 
     def __init__(self, plan: StepPlan, step_no: int):
         self.plan = plan
@@ -105,7 +109,6 @@ class _Pending:
         self.points: List[_Point] = []
         self.det: List[_Det] = []
         self.prefill_finished: List[Request] = []
-        self.t_launch = 0.0
 
 
 def _host_async(*arrays) -> None:
@@ -149,7 +152,8 @@ class DPExecutor:
                  token_budget: Optional[int] = None,
                  prefix_cache: bool = True,
                  pool_undo: str = "rows",
-                 spec_window: int = 0):
+                 spec_window: int = 0,
+                 telemetry: Optional[Telemetry] = None):
         self.physical_id = physical_id
         self.dp_rank = dp_rank
         self.model = model
@@ -201,7 +205,8 @@ class DPExecutor:
         self._dev_stale = True
         self.overlap_stats = {"steps": 0, "planned_ahead": 0,
                               "replans": 0, "drains": 0}
-        self.perf = {"device_busy_s": 0.0}
+        # the engine's telemetry; spans carry this rank as their id
+        self.telemetry = telemetry or Telemetry()
 
     # -- lifecycle --------------------------------------------------------------
 
@@ -258,29 +263,42 @@ class DPExecutor:
 
     # -- two-phase step -----------------------------------------------------------
 
+    def _span(self, name: str):
+        return self.telemetry.span(name, rank=self.dp_rank)
+
+    def _readback(self, logits) -> np.ndarray:
+        """The host waits on the device for a launch's logits."""
+        with self._span(READBACK):
+            return np.asarray(logits)
+
     def plan(self) -> StepPlan:
-        self.block_log.begin_step()
-        plan = self.scheduler.plan_step(self.block_log)
-        if self.cache is not None:
-            # §3.3 device half: either the O(1) functional snapshot of
-            # the whole cache (legacy; pins the pre-step pool buffers),
-            # or — default — capture exactly the rows this step will
-            # write, known at plan time, so rollback is O(write set) and
-            # the pool buffers stay donation-friendly on TPU
-            if self.pool_undo == "snapshot":
-                self.block_log.snapshot_pools(self.cache)
-            else:
-                bids, offs = self._write_manifest(plan)
-                self.block_log.record_pool_undo(capture_pool_rows(
-                    self.cache, self.paged_axes, bids, offs))
-            # prefix-cache COW: seed private divergence blocks from the
-            # shared sources *after* the capture (the copies are part of
-            # the step's write set and roll back with it); one batched
-            # row scatter covers every COW admission of the step
-            self.cache = copy_block_prefixes(self.cache, self.paged_axes,
-                                             plan.cow_copies)
-        self._plan = plan
+        with self._span(PLAN):
+            self.block_log.begin_step()
+            plan = self.scheduler.plan_step(self.block_log)
+            if self.cache is not None:
+                with self._span(UNDO_CAPTURE):
+                    self._capture_undo(plan)
+            self._plan = plan
         return plan
+
+    def _capture_undo(self, plan: StepPlan) -> None:
+        # §3.3 device half: either the O(1) functional snapshot of the
+        # whole cache (legacy; pins the pre-step pool buffers), or —
+        # default — capture exactly the rows this step will write, known
+        # at plan time, so rollback is O(write set) and the pool buffers
+        # stay donation-friendly on TPU
+        if self.pool_undo == "snapshot":
+            self.block_log.snapshot_pools(self.cache)
+        else:
+            bids, offs = self._write_manifest(plan)
+            self.block_log.record_pool_undo(capture_pool_rows(
+                self.cache, self.paged_axes, bids, offs))
+        # prefix-cache COW: seed private divergence blocks from the
+        # shared sources *after* the capture (the copies are part of the
+        # step's write set and roll back with it); one batched row
+        # scatter covers every COW admission of the step
+        self.cache = copy_block_prefixes(self.cache, self.paged_axes,
+                                         plan.cow_copies)
 
     def _write_manifest(self, plan: StepPlan
                         ) -> Tuple[np.ndarray, np.ndarray]:
@@ -348,65 +366,76 @@ class DPExecutor:
         inputs come from the device-resident chain instead of the host
         vector, and a jitted epilogue samples the step's tokens
         on-device so the next step can launch before this one drains."""
+        with self._span(LAUNCH):
+            return self._dispatch(ctx, step_no, predict)
+
+    def _dispatch(self, ctx, step_no: int, predict: bool) -> _Pending:
         plan, self._plan = self._plan, None
         assert plan is not None, "compute without plan"
         pend = _Pending(plan, step_no)
         params, runtime = ctx.params, ctx.runtime
 
         if plan.chunks or plan.spec:
-            tokens, page = build_chunk_context(
-                plan.chunks + plan.spec, self.scheduler.block_tables,
-                width=self.chunk_tokens, max_blk=self.max_blk,
-                block_size=self.block_size, trash_block=self.trash_block)
-            if predict:
-                tokens = self._chain_chunk_tokens(tokens, plan)
-            logits, self.cache = ctx.chunk_fn()(
-                params, self.cache, tokens, page, runtime)
-            pend.chunk_logits = logits
+            with self._span(LAUNCH_CHUNK):
+                tokens, page = build_chunk_context(
+                    plan.chunks + plan.spec, self.scheduler.block_tables,
+                    width=self.chunk_tokens, max_blk=self.max_blk,
+                    block_size=self.block_size,
+                    trash_block=self.trash_block)
+                if predict:
+                    tokens = self._chain_chunk_tokens(tokens, plan)
+                logits, self.cache = ctx.chunk_fn()(
+                    params, self.cache, tokens, page, runtime)
+                pend.chunk_logits = logits
 
         assert not (predict and plan.prefills), \
             "overlap requires chunked admission (no whole-prompt installs)"
         for req in plan.prefills:
             toks = req.tokens_so_far
-            bucket = next_bucket(len(toks), self.max_seq)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :len(toks)] = toks
-            lengths = np.asarray([len(toks)], np.int32)
-            prefill_fn = ctx.prefill_fn(bucket)
-            last_logits, raw = prefill_fn(params, padded, lengths, runtime)
-            nblk = max_blocks_per_seq(bucket, self.block_size)
-            bids = padded_block_ids(
-                self.scheduler.block_tables[req.req_id].blocks, nblk,
-                self.trash_block)
-            install_fn = ctx.install_fn(bucket)
-            self.cache = install_fn(self.cache, raw, bids,
-                                    np.int32(req.batch_slot))
-            req.prefill_pos = len(toks)
-            self.scheduler.note_prefill_done(len(toks))
-            tok = int(sample(np.asarray(last_logits), self.sampling,
-                             step=req.num_tokens)[0])
-            req.output_tokens.append(tok)
-            req.note_token()
-            req.state = RequestState.RUNNING
-            self.last_token[req.batch_slot] = tok
-            if req.done:
-                self.scheduler.finish(req, self.block_log)
-                req.finish_time = time.monotonic()
-                pend.prefill_finished.append(req)
+            with self._span(LAUNCH_PREFILL):
+                bucket = next_bucket(len(toks), self.max_seq)
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :len(toks)] = toks
+                lengths = np.asarray([len(toks)], np.int32)
+                prefill_fn = ctx.prefill_fn(bucket)
+                last_logits, raw = prefill_fn(params, padded, lengths,
+                                              runtime)
+                nblk = max_blocks_per_seq(bucket, self.block_size)
+                bids = padded_block_ids(
+                    self.scheduler.block_tables[req.req_id].blocks, nblk,
+                    self.trash_block)
+                install_fn = ctx.install_fn(bucket)
+                self.cache = install_fn(self.cache, raw, bids,
+                                        np.int32(req.batch_slot))
+            last_logits = self._readback(last_logits)
+            with self._span(SAMPLE):
+                req.prefill_pos = len(toks)
+                self.scheduler.note_prefill_done(len(toks))
+                tok = int(sample(last_logits, self.sampling,
+                                 step=req.num_tokens)[0])
+                req.output_tokens.append(tok)
+                req.note_token()
+                req.state = RequestState.RUNNING
+                self.last_token[req.batch_slot] = tok
+                if req.done:
+                    self.scheduler.finish(req, self.block_log)
+                    req.finish_time = time.monotonic()
+                    pend.prefill_finished.append(req)
 
         if plan.decode:
-            page = build_page_context(
-                plan.decode, self.scheduler.block_tables,
-                max_batch=self.max_batch, max_blk=self.max_blk,
-                block_size=self.block_size, trash_block=self.trash_block)
-            tokens = (self._dev_chain() if predict
-                      else np.asarray(self.last_token))
-            logits, new_cache = ctx.decode_fn(
-                params, self.cache, tokens, page, runtime)
-            self.cache = new_cache
-            pend.decode_logits = logits
+            with self._span(LAUNCH_DECODE):
+                page = build_page_context(
+                    plan.decode, self.scheduler.block_tables,
+                    max_batch=self.max_batch, max_blk=self.max_blk,
+                    block_size=self.block_size,
+                    trash_block=self.trash_block)
+                tokens = (self._dev_chain() if predict
+                          else np.asarray(self.last_token))
+                logits, new_cache = ctx.decode_fn(
+                    params, self.cache, tokens, page, runtime)
+                self.cache = new_cache
+                pend.decode_logits = logits
 
-        pend.t_launch = time.perf_counter()
         if predict:
             self._launch_predict(pend)
         return pend
@@ -416,68 +445,72 @@ class DPExecutor:
         """Force the step's logits and commit its outcome on the host
         (the authoritative sampler).  ``chunk_book=False`` skips the
         chunk-piece bookkeeping the overlap launch already applied."""
-        plan = pend.plan
         finished: List[Request] = []
-        t_done = None
-
         if pend.chunk_logits is not None:
-            logits = np.asarray(pend.chunk_logits)
-            t_done = time.perf_counter()
-            row = 0
-            for piece in plan.chunks:
-                req = piece.req
-                if chunk_book:
-                    req.prefill_pos = piece.start + piece.length
-                    self.scheduler.note_chunk_done(piece, self.block_log)
-                if piece.last:
-                    # seed by sequence position, not engine step: the
-                    # token is a pure function of (seed, prefix,
-                    # position) and survives replay on any executor of
-                    # any fleet instance
-                    tok = int(sample(logits[row + piece.length - 1][None],
-                                     self.sampling,
-                                     step=req.num_tokens)[0])
-                    req.output_tokens.append(tok)
-                    req.note_token()
-                    req.state = RequestState.RUNNING
-                    self.last_token[req.batch_slot] = tok
-                    if req.done or req.num_tokens >= self.max_seq:
-                        self.scheduler.finish(req, self.block_log)
-                        req.finish_time = time.monotonic()
-                        finished.append(req)
-                row += piece.length
-            if plan.spec:
-                finished.extend(self._verify_spec(plan, logits, row))
-
+            logits = self._readback(pend.chunk_logits)
+            with self._span(SAMPLE):
+                finished.extend(self._commit_chunks(pend.plan, logits,
+                                                    chunk_book))
         finished.extend(pend.prefill_finished)
-
         if pend.decode_logits is not None:
-            logits = np.asarray(pend.decode_logits)
-            t_done = time.perf_counter()
-            # one batched sample over the whole decode batch (the
-            # per-request loop serialized B host round trips per step)
-            slots = np.fromiter((r.batch_slot for r in plan.decode),
-                                np.intp, count=len(plan.decode))
-            positions = np.fromiter((r.num_tokens for r in plan.decode),
-                                    np.int64, count=len(plan.decode))
-            toks = sample(logits[slots], self.sampling, step=positions)
-            for req, tok in zip(plan.decode, toks):
-                tok = int(tok)
+            logits = self._readback(pend.decode_logits)
+            with self._span(SAMPLE):
+                finished.extend(self._commit_decode(pend.plan, logits))
+        self._dev_stale = True
+        self.steps_done += 1
+        return finished
+
+    def _commit_chunks(self, plan: StepPlan, logits: np.ndarray,
+                       chunk_book: bool) -> List[Request]:
+        finished: List[Request] = []
+        row = 0
+        for piece in plan.chunks:
+            req = piece.req
+            if chunk_book:
+                req.prefill_pos = piece.start + piece.length
+                self.scheduler.note_chunk_done(piece, self.block_log)
+            if piece.last:
+                # seed by sequence position, not engine step: the token
+                # is a pure function of (seed, prefix, position) and
+                # survives replay on any executor of any fleet instance
+                tok = int(sample(logits[row + piece.length - 1][None],
+                                 self.sampling, step=req.num_tokens)[0])
                 req.output_tokens.append(tok)
                 req.note_token()
+                req.state = RequestState.RUNNING
                 self.last_token[req.batch_slot] = tok
-                # decode-grown blocks publish in the prefix cache as
-                # they fill (carry-over (f)) — register before a
-                # possible finish so the blocks park cache-addressable
-                self.scheduler.note_decode_progress(req, self.block_log)
                 if req.done or req.num_tokens >= self.max_seq:
                     self.scheduler.finish(req, self.block_log)
                     req.finish_time = time.monotonic()
                     finished.append(req)
-        if t_done is not None:
-            self.perf["device_busy_s"] += t_done - pend.t_launch
-        self._dev_stale = True
-        self.steps_done += 1
+            row += piece.length
+        if plan.spec:
+            finished.extend(self._verify_spec(plan, logits, row))
+        return finished
+
+    def _commit_decode(self, plan: StepPlan,
+                       logits: np.ndarray) -> List[Request]:
+        finished: List[Request] = []
+        # one batched sample over the whole decode batch (the
+        # per-request loop serialized B host round trips per step)
+        slots = np.fromiter((r.batch_slot for r in plan.decode),
+                            np.intp, count=len(plan.decode))
+        positions = np.fromiter((r.num_tokens for r in plan.decode),
+                                np.int64, count=len(plan.decode))
+        toks = sample(logits[slots], self.sampling, step=positions)
+        for req, tok in zip(plan.decode, toks):
+            tok = int(tok)
+            req.output_tokens.append(tok)
+            req.note_token()
+            self.last_token[req.batch_slot] = tok
+            # decode-grown blocks publish in the prefix cache as they
+            # fill (carry-over (f)) — register before a possible finish
+            # so the blocks park cache-addressable
+            self.scheduler.note_decode_progress(req, self.block_log)
+            if req.done or req.num_tokens >= self.max_seq:
+                self.scheduler.finish(req, self.block_log)
+                req.finish_time = time.monotonic()
+                finished.append(req)
         return finished
 
     def _verify_spec(self, plan: StepPlan, logits: np.ndarray,
@@ -534,7 +567,8 @@ class DPExecutor:
 
     def commit(self) -> None:
         """Step boundary reached: the undo log is no longer needed."""
-        self.block_log.begin_step()  # clears; committed counter advances
+        with self._span(COMMIT):
+            self.block_log.begin_step()  # clears; committed counter advances
 
     def rollback_inflight(self) -> int:
         """§3.3: undo every uncommitted step — host block tables from
@@ -635,23 +669,21 @@ class DPExecutor:
         """Plan-ahead half: plan in a (possibly stacked) undo frame,
         capture the write set, and dispatch.  Returns None when the
         scheduler has nothing plannable (pool/budget pressure)."""
-        if stacked:
-            self.block_log.push_frame()
-        plan = self.scheduler.plan_step(self.block_log)
-        if plan.empty:
+        with self._span(PLAN):
             if stacked:
-                self.block_log.undo_newest(self.block_manager,
-                                           self.scheduler.block_tables)
-            return None
-        # the capture gathers post-(k-1) row values: it dispatches after
-        # k-1's compute in device program order, which is exactly what a
-        # rollback of step k alone must restore
-        bids, offs = self._write_manifest(plan)
-        self.block_log.record_pool_undo(capture_pool_rows(
-            self.cache, self.paged_axes, bids, offs))
-        self.cache = copy_block_prefixes(self.cache, self.paged_axes,
-                                         plan.cow_copies)
-        self._plan = plan
+                self.block_log.push_frame()
+            plan = self.scheduler.plan_step(self.block_log)
+            if plan.empty:
+                if stacked:
+                    self.block_log.undo_newest(self.block_manager,
+                                               self.scheduler.block_tables)
+                return None
+            # the capture gathers post-(k-1) row values: it dispatches
+            # after k-1's compute in device program order, which is
+            # exactly what a rollback of step k alone must restore
+            with self._span(UNDO_CAPTURE):
+                self._capture_undo(plan)
+            self._plan = plan
         return self.begin_compute(ctx, step_no, predict=True)
 
     def _dev_chain(self):
@@ -905,30 +937,30 @@ class DPExecutor:
         self.overlap_stats["drains"] += 1
         ch = de = None
         if prev.chunk_logits is not None:
-            ch = np.asarray(prev.chunk_logits)
-            prev.chunk_logits = ch
+            ch = prev.chunk_logits = self._readback(prev.chunk_logits)
         if prev.decode_logits is not None:
-            de = np.asarray(prev.decode_logits)
-            prev.decode_logits = de
-        self.perf["device_busy_s"] += time.perf_counter() - prev.t_launch
-        actual = self._actual_outcome(prev, ch, de)
-        if not self._diverged(prev, actual):
-            finished = self._confirm(prev, actual)
-            self.block_log.commit_oldest()
+            de = prev.decode_logits = self._readback(prev.decode_logits)
+        with self._span(SAMPLE):
+            actual = self._actual_outcome(prev, ch, de)
+            diverged = self._diverged(prev, actual)
+            if diverged:
+                # reconcile: unwind the mispredicted plan-ahead step
+                # first (its pool capture holds post-prev values, so it
+                # must restore before prev's own spec-reject restores),
+                # then pop prev's guesses
+                if nxt is not None:
+                    self._unwind_pending(nxt)
+                self._unwind_overlay(prev)
+            else:
+                finished = self._confirm(prev, actual)
+        if diverged:
+            # replay prev's true outcome through the lockstep commit code
+            finished = self.finish_compute(prev, chunk_book=False)
+        else:
             self.steps_done += 1
-            return finished, False
-        # reconcile: unwind the mispredicted plan-ahead step first (its
-        # pool capture holds post-prev values, so it must restore before
-        # prev's own spec-reject restores), then pop prev's guesses and
-        # replay its true outcome through the lockstep commit code
-        if nxt is not None:
-            self._unwind_pending(nxt)
-        self._unwind_overlay(prev)
-        prev.t_launch = time.perf_counter()   # busy already accounted
-        finished = self.finish_compute(prev, chunk_book=False)
-        self.block_log.commit_oldest()
-        self._dev_stale = True
-        return finished, True
+        with self._span(COMMIT):
+            self.block_log.commit_oldest()
+        return finished, diverged
 
     # -- KV-block migration (§3.2, streaming path) --------------------------------
 

@@ -421,8 +421,10 @@ class ServingFrontend:
                     "steps": eng.step_no,
                     "masked_expert_fraction":
                         self._masked_fraction(eng),
-                    "host_gap_fraction":
-                        round(eng.host_gap_fraction(), 6),
+                    "telemetry": eng.telemetry.summary(),
+                    "graph_cache": {
+                        "compiles": len(eng.graph_cache.timings),
+                        "hits": eng.graph_cache.hits},
                     "overlap": eng.overlap_stats(),
                     "recoveries": [rep.summary()
                                    for rep in eng.reports],

@@ -97,7 +97,10 @@ def test_overlap_token_exact_vs_lockstep(tmp_path, arch, cfg_fn):
     st = eng.overlap_stats()
     assert st["planned_ahead"] > 0        # the pipeline actually piped
     assert st["replans"] == 0             # greedy device argmax is exact
-    assert eng.host_gap_fraction() < 1.0
+    summ = eng.telemetry.summary()
+    assert 0.0 < summ["host_share"] < 1.0
+    assert "repro.plan" in summ["phase_ms"]
+    assert "repro.readback" in summ["phase_ms"]
 
 
 @pytest.mark.parametrize("temperature", [0.3, 0.8])

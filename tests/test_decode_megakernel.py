@@ -331,12 +331,8 @@ def test_engine_fail_rank_and_mask_zero_recompile(tmp_path):
                        precompile_failure_scenarios=False)
     rng = np.random.default_rng(4)
 
-    def real_compiles():
-        return sum(1 for t in eng.graph_cache.timings
-                   if t.compile_s > 0.01)
-
     _serve(eng, cfg, [list(rng.integers(0, cfg.vocab_size, 12))])
-    n0 = real_compiles()
+    n0 = len(eng.graph_cache.timings)
     # recovery's two runtime mutations, applied as the §3.4 data edit
     eng.expert_map.fail_rank(1)
     eng.expert_map.mask_experts(
@@ -345,7 +341,7 @@ def test_engine_fail_rank_and_mask_zero_recompile(tmp_path):
                     for s in eng.expert_map.replicas_of(e))])
     eng.runtime = eng.expert_map.runtime()
     out = _serve(eng, cfg, [list(rng.integers(0, cfg.vocab_size, 9))])
-    assert real_compiles() == n0
+    assert len(eng.graph_cache.timings) == n0   # no miss compiled
     assert out and len(out[0]) == 6
 
 

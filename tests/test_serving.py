@@ -306,3 +306,19 @@ def test_compile_cache_dir_honours_env(monkeypatch, tmp_path):
     assert jax.config.jax_compilation_cache_dir == gc_.persist_dir
     with open(os.path.join(root, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+def test_graph_cache_lists_compiles_and_counts_hits():
+    """``timings`` holds one entry per compile; a lookup that finds a
+    ready executable only counts a hit, and returns its own timing."""
+    from repro.core import graph_cache
+    gc_ = graph_cache.GraphCache(persist=False)
+    spec = (jax.ShapeDtypeStruct((3,), jnp.float32),)
+    fn, tm = gc_.get_or_compile(("k",), lambda a: a + 1, spec)
+    assert tm.source == "cold" and gc_.timings == [tm] and gc_.hits == 0
+    for n in (1, 2):
+        again, hit = gc_.get_or_compile(("k",), None, None)
+        assert again is fn and gc_.hits == n
+        assert (hit.source, hit.read_cache_s, hit.compile_s) == (
+            "precompiled", 0.0, 0.0)
+    assert gc_.timings == [tm]

@@ -226,12 +226,8 @@ def test_spec_failrank_mask_zero_recompile(tmp_path):
     cfg, eng = _engine(tmp_path, "zc", num_dp=2, spec_window=6,
                        precompile_failure_scenarios=False)
 
-    def real_compiles():
-        return sum(1 for t in eng.graph_cache.timings
-                   if t.compile_s > 0.01)
-
     _serve(eng, [PAT_A * 4], max_new=8)
-    n0 = real_compiles()
+    n0 = len(eng.graph_cache.timings)
     eng.expert_map.fail_rank(1)
     eng.expert_map.mask_experts(
         [e for e in range(cfg.moe.num_experts)
@@ -239,7 +235,7 @@ def test_spec_failrank_mask_zero_recompile(tmp_path):
                     for s in eng.expert_map.replicas_of(e))])
     eng.runtime = eng.expert_map.runtime()
     _serve(eng, [PAT_B * 10], max_new=12)
-    assert real_compiles() == n0
+    assert len(eng.graph_cache.timings) == n0   # no miss compiled
     assert eng.prefill_stats()["spec_windows"] > 0
 
 
