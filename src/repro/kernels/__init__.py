@@ -25,5 +25,6 @@ Kernels:
 Every kernel passes ``pltpu.CompilerParams`` directly.
 ``paged_attention``, ``moe_fused``
 and ``decode_megakernel`` are compiled for a described v5e at
-qwen2-moe-a2.7b widths by ``tests/test_tpu_compile.py``.
+qwen2-moe-a2.7b widths by ``tests/test_tpu_compile.py``; paged attention
+also at internlm2-20b's and minicpm3-4b's (MLA) widths.
 """

@@ -36,7 +36,8 @@ from repro.serving.cache_ops import (capture_pool_rows,
                                      restore_pool_rows_subset,
                                      scatter_request_blocks)
 from repro.serving.kvcache import (build_chunk_context, build_page_context,
-                                   max_blocks_per_seq, padded_block_ids)
+                                   max_blocks_per_seq, padded_block_ids,
+                                   walked_pages)
 from repro.serving.request import Request, RequestState
 from repro.serving.sampling import (SamplingParams, device_predict, sample,
                                     seeded_uniforms, spec_verify)
@@ -382,6 +383,7 @@ class DPExecutor:
                     width=self.chunk_tokens, max_blk=self.max_blk,
                     block_size=self.block_size,
                     trash_block=self.trash_block)
+                self._count_pages(page)
                 if predict:
                     tokens = self._chain_chunk_tokens(tokens, plan)
                 logits, self.cache = ctx.chunk_fn()(
@@ -429,6 +431,7 @@ class DPExecutor:
                     max_batch=self.max_batch, max_blk=self.max_blk,
                     block_size=self.block_size,
                     trash_block=self.trash_block)
+                self._count_pages(page)
                 tokens = (self._dev_chain() if predict
                           else np.asarray(self.last_token))
                 logits, new_cache = ctx.decode_fn(
@@ -439,6 +442,14 @@ class DPExecutor:
         if predict:
             self._launch_predict(pend)
         return pend
+
+    def _count_pages(self, page: Dict[str, np.ndarray]) -> None:
+        """Charge a launch's paged-attention walk to the telemetry: the
+        pages its rows' valid windows span, and those its tables hold."""
+        sl = page["seq_lens"]
+        self.telemetry.count_pages(
+            walked_pages(sl, self.block_size, self.model.cfg.sliding_window),
+            sl.size * self.max_blk)
 
     def finish_compute(self, pend: _Pending,
                        chunk_book: bool = True) -> List[Request]:

@@ -32,6 +32,17 @@ def max_blocks_per_seq(max_seq: int, block_size: int) -> int:
     return (max_seq + block_size - 1) // block_size
 
 
+def walked_pages(seq_lens, block_size: int, window: int = 0) -> int:
+    """Pages ``paged_attention`` walks for rows of these valid lengths:
+    each row's pages from the one holding its first attended position
+    (``seq_len - window`` under a sliding window; 0 is none) to the one
+    holding its last; a row of ``seq_len`` 0 walks none."""
+    sl = np.asarray(seq_lens, np.int64)
+    lo = np.maximum(sl - window, 0) // block_size if window else 0
+    hi = -(-sl // block_size)
+    return int(np.maximum(hi - lo, 0).sum())
+
+
 def table_array(tables: Dict[int, "BlockTable"], order: List[int],
                 max_blk: int) -> np.ndarray:
     """Pack host-side block tables into the (B, max_blk) device array."""

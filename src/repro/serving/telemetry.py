@@ -11,6 +11,8 @@ span's self time is its duration less that of its child spans.
 ``InferenceEngine.step()`` gives exactly one :class:`StepRecord` (wall
 time, self seconds per span name, compiles), kept in a bounded ring.
 The spans of the last finished step are kept as well, for ``summary()``.
+``count_pages`` adds a paged-attention launch's walked and table pages to
+the open step's record.
 Every span, in a step or not (engine build, revive), also feeds an
 aggregate per name: count, total and self seconds, compiles.
 
@@ -66,7 +68,8 @@ class StepRecord:
     """One ``InferenceEngine.step()``: its wall time, the self seconds of
     each span name in it, and the compiles charged to it."""
     __slots__ = ("index", "step", "wall_s", "self_s", "compiles",
-                 "compile_s", "_self_ns", "_spans")
+                 "compile_s", "pages_walked", "pages_in_tables", "_self_ns",
+                 "_spans")
 
     def __init__(self, index: int, step: int):
         self.index = index              # ordinal among this telemetry's steps
@@ -75,6 +78,10 @@ class StepRecord:
         self.self_s: Dict[str, float] = {}
         self.compiles = 0
         self.compile_s = 0.0
+        # paged-attention pages the step's launches walk, and hold in
+        # their block tables
+        self.pages_walked = 0
+        self.pages_in_tables = 0
         self._self_ns: Dict[str, int] = {}
         # (name, parent, t0_ns, t1_ns) while the step is open
         self._spans: List[SpanEntry] = []
@@ -184,6 +191,14 @@ class Telemetry:
         finally:
             sink[key] = sink.get(key, 0.0) + sp.seconds
 
+    def count_pages(self, walked: int, in_tables: int) -> None:
+        """A paged-attention launch in the open step walks ``walked`` of
+        the ``in_tables`` pages its block tables hold."""
+        rec = self._rec
+        if rec is not None:
+            rec.pages_walked += walked
+            rec.pages_in_tables += in_tables
+
     @property
     def step_compiles(self) -> int:
         """Compiles charged so far to the step that is open (0 outside a
@@ -229,7 +244,9 @@ class Telemetry:
         span name so far.  ``host_share`` is the part of the steps' wall
         time in which the host was not waiting on a readback.
         ``last_step`` lays out the last finished step: its spans in start
-        order as ``[name, parent, start_ms, ms]`` from the step's start."""
+        order as ``[name, parent, start_ms, ms]`` from the step's start.
+        ``paged_attn`` sums the pages the steps' paged-attention launches
+        walked and the pages their block tables held."""
         recs = [r for r in self.records if r.index >= since]
         n = len(recs)
         wall = sum(r.wall_s for r in recs)
@@ -245,6 +262,9 @@ class Telemetry:
             "host_share": (wall - waited) / wall if wall > 0 else 0.0,
             "compiles": sum(r.compiles for r in recs),
             "compile_s": sum(r.compile_s for r in recs),
+            "paged_attn": {
+                "pages_walked": sum(r.pages_walked for r in recs),
+                "pages_in_tables": sum(r.pages_in_tables for r in recs)},
             "spans": {k: {"count": a[0], "total_s": a[1] * 1e-9,
                           "self_s": a[2] * 1e-9, "compiles": a[3],
                           "compile_s": a[4]}

@@ -3,10 +3,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 from repro.kernels.expert_ffn import expert_ffn_pallas
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import (paged_attention_pallas,
+                                            pages_per_step)
 from repro.kernels.router_topk import router_topk_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
 
@@ -52,23 +54,58 @@ def test_expert_ffn(E, C, D, F, dtype):
                                atol=tol)
 
 
-@pytest.mark.parametrize("B,H,Hkv,Dh,bs,mb", [
-    (2, 4, 4, 64, 16, 3),      # MHA
-    (3, 8, 2, 64, 16, 4),      # GQA
-    (1, 16, 8, 128, 32, 2),
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.mark.parametrize("B,H,Hkv,Dh,bs,mb,dtype,sl,st", [
+    pytest.param(2, 4, 4, 64, 16, 3, F32, None, None,          # MHA
+                 id="2-4-4-64-16-3"),
+    pytest.param(3, 8, 2, 64, 16, 4, F32, None, None,          # GQA
+                 id="3-8-2-64-16-4"),
+    pytest.param(1, 16, 8, 128, 32, 2, F32, None, None,
+                 id="1-16-8-128-32-2"),
+    pytest.param(4, 8, 2, 64, 16, 5, F32, [0, 33, 0, 80], None,
+                 id="idle-rows"),
+    pytest.param(3, 8, 2, 64, 16, 4, F32, [17, 49, 63], None,
+                 id="partial-last-page"),
+    pytest.param(3, 8, 2, 64, 16, 40, F32, [640, 300, 17], [300, 290, 0],
+                 id="window-skips-leading-pages"),
+    pytest.param(2, 4, 4, 64, 16, 45, F32, [720, 530], [0, 515],
+                 id="max-blk-not-divisible"),
+    pytest.param(3, 48, 8, 128, 16, 20, BF16, [300, 0, 257], None,
+                 id="internlm2-gqa-bf16"),
+    pytest.param(2, 16, 1, 576, 16, 6, BF16, [90, 33], [40, 0],
+                 id="mla-latent-bf16"),
 ])
-def test_paged_attention(B, H, Hkv, Dh, bs, mb):
+def test_paged_attention(B, H, Hkv, Dh, bs, mb, dtype, sl, st):
+    """The kernel against its oracle under the TPU interpreter: DMAs land
+    only when waited on, unwritten VMEM reads NaN, races are detected."""
     nb = mb * B + 2
     ks = jax.random.split(jax.random.fold_in(KEY, B * H * bs), 5)
-    q = jax.random.normal(ks[0], (B, H, Dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (nb, bs, Hkv, Dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (nb, bs, Hkv, Dh), jnp.float32)
+    q = jax.random.normal(ks[0], (B, H, Dh), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[1], (nb, bs, Hkv, Dh),
+                           jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], (nb, bs, Hkv, Dh),
+                           jnp.float32).astype(dtype)
     bt = jax.random.randint(ks[3], (B, mb), 0, nb)
-    sl = jax.random.randint(ks[4], (B,), 1, mb * bs + 1)
-    o1 = paged_attention_pallas(q, kp, vp, bt, sl, interpret=True)
-    o2 = ref.paged_attention_ref(q, kp, vp, bt, sl)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-4,
-                               atol=1e-5)
+    sl = (jax.random.randint(ks[4], (B,), 1, mb * bs + 1) if sl is None
+          else jnp.asarray(sl, jnp.int32))
+    st = None if st is None else jnp.asarray(st, jnp.int32)
+    P = pages_per_step(bs, Hkv, -(-Dh // 128) * 128, q.dtype.itemsize, mb)
+    if mb == 45:
+        assert mb % P, P            # the last grid step's block is short
+    o1 = paged_attention_pallas(
+        q, kp, vp, bt, sl, st,
+        interpret=pltpu.InterpretParams(detect_races=True))
+    o2 = ref.paged_attention_ref(q, kp, vp, bt, sl, st)
+    # the oracle rounds its probabilities to the pools' dtype, the kernel
+    # keeps them in f32
+    tol = (1e-4, 1e-5) if dtype == F32 else (2e-2, 2e-2)
+    np.testing.assert_allclose(np.asarray(o1, np.float32),
+                               np.asarray(o2, np.float32), rtol=tol[0],
+                               atol=tol[1])
+    idle = np.asarray(sl) == 0
+    assert not np.asarray(o1, np.float32)[idle].any()
 
 
 @pytest.mark.parametrize("B,S,d,N,block_d,chunk", [

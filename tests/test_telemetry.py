@@ -155,6 +155,50 @@ def test_summary_since_leaves_out_earlier_steps():
     assert T.READBACK in s["phase_ms"]
 
 
+@pytest.mark.parametrize("window,walked", [(0, (11, 6)), (6, (4, 6))],
+                         ids=["full", "sliding"])
+def test_paged_attn_counter_sums_walked_and_table_pages(window, walked):
+    """A launch counts the pages its rows' valid windows span (the pages
+    the paged-attention kernel walks) and the pages its tables hold;
+    ``summary()`` sums both over the steps it covers."""
+    from types import SimpleNamespace as NS
+
+    from repro.serving.executor import DPExecutor
+    from repro.serving.kvcache import build_chunk_context, build_page_context
+    from repro.serving.sampling import SamplingParams
+    cfg = get_smoke_config("qwen2-moe-a2.7b").with_sliding_window(window)
+    tel = T.Telemetry()
+    ex = DPExecutor(physical_id=0, dp_rank=0, model=Model(cfg), max_batch=4,
+                    max_seq=32, num_blocks=16, block_size=4,
+                    sampling=SamplingParams(), telemetry=tel)
+    assert ex.max_blk == 8
+    tables = {1: NS(blocks=[3, 4, 5]), 2: NS(blocks=list(range(7, 15)))}
+    dec = build_page_context(
+        [NS(req_id=1, batch_slot=1, num_tokens=10),
+         NS(req_id=2, batch_slot=3, num_tokens=32)], tables, max_batch=4,
+        max_blk=8, block_size=4, trash_block=16)
+    _, chunk = build_chunk_context(
+        [NS(req=NS(req_id=2), start=5, length=3, tokens=list(range(32)))],
+        tables, width=4, max_blk=8, block_size=4, trash_block=16)
+    assert list(dec["seq_lens"]) == [0, 10, 0, 32]
+    assert list(chunk["seq_lens"]) == [6, 7, 8, 0]
+    for page, n in zip((dec, chunk), walked):
+        # the pages holding any position of [seq_len - window, seq_len)
+        spans = sum(len({pos // 4 for pos in range(
+            max(sl - window, 0) if window else 0, sl)})
+            for sl in page["seq_lens"])
+        assert spans == n
+    with tel.step(0):
+        ex._count_pages(dec)
+    with tel.step(1):
+        ex._count_pages(chunk)
+    ex._count_pages(chunk)              # outside a step: not counted
+    assert tel.summary()["paged_attn"] == {
+        "pages_walked": sum(walked), "pages_in_tables": 64}
+    assert tel.summary(since=1)["paged_attn"] == {
+        "pages_walked": walked[1], "pages_in_tables": 32}
+
+
 # -- in the engine -------------------------------------------------------------
 
 
@@ -181,6 +225,8 @@ def test_each_step_gives_one_record_with_nested_phases(tmp_path, overlap):
     summ = tel.summary()
     assert 0.0 < summ["host_share"] < 1.0
     assert summ["steps"] == tel.n_steps
+    pa = summ["paged_attn"]
+    assert 0 < pa["pages_walked"] < pa["pages_in_tables"]
 
 
 def test_spans_land_on_the_profiler_host_plane_in_order(tmp_path):

@@ -2,7 +2,8 @@
 
 Nothing runs: the TPU compiler (installed with libtpu) compiles each
 kernel for one chip of a ``v5e:2x2`` topology that is described, not
-attached, at qwen2-moe-a2.7b widths in bfloat16.  This catches what
+attached, at qwen2-moe-a2.7b widths in bfloat16; paged attention also at
+internlm2-20b's and at minicpm3-4b's MLA latent widths.  This catches what
 interpret mode cannot — tiling-rule violations, scalar accesses to vector
 memory, VMEM overflow — before any chip time is spent.
 
@@ -76,6 +77,30 @@ def test_paged_attention_compiles(chip, B):
              chip((B, H, Dh), BF), chip((NB, BS, Hkv, Dh), BF),
              chip((NB, BS, Hkv, Dh), BF), chip((B, MAX_BLK), jnp.int32),
              chip((B,), jnp.int32))
+
+
+def _paged_widths(name):
+    """(H, Hkv, Dh) a configuration's paged-attention call runs at: MLA
+    attends one latent head of R + dr lanes."""
+    cfg = get_config(name)
+    if cfg.mla is not None:
+        return (cfg.num_heads, 1,
+                cfg.mla.kv_lora_rank + cfg.mla.qk_rope_head_dim)
+    return cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim()
+
+
+@pytest.mark.parametrize("name,B,max_blk,nb", [
+    ("internlm2-20b", 8, 208, 1665),      # the batch cell's decode call
+    ("internlm2-20b", 32, 208, 1665),     # and its chunk call
+    ("minicpm3-4b", 8, 160, 1025),        # MLA latent pool, 288 lanes
+])
+def test_paged_attention_compiles_at(chip, name, B, max_blk, nb):
+    H, Hkv, Dh = _paged_widths(name)
+    _compile(lambda q, k, v, bt, sl, st: paged_attention_pallas(
+                 q, k, v, bt, sl, st),
+             chip((B, H, Dh), BF), chip((nb, BS, Hkv, Dh), BF),
+             chip((nb, BS, Hkv, Dh), BF), chip((B, max_blk), jnp.int32),
+             chip((B,), jnp.int32), chip((B,), jnp.int32))
 
 
 @pytest.mark.parametrize("T", [4, 32])
