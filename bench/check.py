@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 After the window has closed and the program's state is freed, the plain
-reference of the configuration (``bench/reference/<name>.py``) runs once
+reference of the configuration (``layer`` of its architecture module,
+``bench/reference/<name>.py``, passed as ``arch``) runs once
 over each sampled request: its prompt followed by the tokens the program
 served, teacher-forced.  At every position that produced a served token
 it reads the gap by which the served token's logit lies below the
@@ -13,8 +14,9 @@ were checked, over the whole sample or over the requests served before
 the fault and after the revive apart: ``bench.harness._checks``).
 
 The reference makes its weights again from the seed, one layer at a time
-(``bench.weights``), in float32 (``highest`` matmul precision), and holds
-the hidden states of every sampled request between layers.
+(``bench.weights``, the leaves the module lists for that layer's index),
+in float32 (``highest`` matmul precision), and holds the hidden states of
+every sampled request between layers.
 
 The control (``python bench/control.py``) runs the same reference with
 its matrix products rounded to float8 and reads the gap of the token
@@ -23,7 +25,6 @@ that float8 puts first, at the same positions.
 from __future__ import annotations
 
 import functools
-import importlib
 from typing import List, Sequence
 
 import jax
@@ -35,14 +36,11 @@ from bench.model import Shape
 from bench.reference.common import mm, rms_norm
 
 
-def reference_module(name: str):
-    return importlib.import_module(f"bench.reference.{name}")
-
-
-@functools.partial(jax.jit, static_argnames=("s", "dtype"))
-def _layer_weights(key, layer, s, dtype):
-    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
-                                  W.layer_weights(key, s, layer, dtype))
+@functools.partial(jax.jit, static_argnames=("arch", "s", "like", "dtype"))
+def _layer_weights(key, layer, arch, s, like, dtype):
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32),
+        W.layer_weights(key, arch, s, layer, dtype, like))
 
 
 def _padded(n: int, to: int = 128) -> int:
@@ -72,7 +70,7 @@ class Served:
         return np.asarray(self.tokens[self.n_prompt:], np.int32)
 
 
-def final_hidden(seed: int, s: Shape, ref_name: str, seqs: List[Served],
+def final_hidden(seed: int, arch, s: Shape, seqs: List[Served],
                  precision: str = "f32", dtype=jnp.bfloat16,
                  length: int = 0) -> list:
     """Normed last hidden state at each sequence's served rows, after the
@@ -91,10 +89,13 @@ def final_hidden(seed: int, s: Shape, ref_name: str, seqs: List[Served],
     del emb
     lives = [jnp.ones((max(s.experts, 1),), bool) if q.live is None
              else jnp.asarray(q.live) for q in seqs]
-    layer = _layer_fn(ref_name, s, precision)
+    layer = _layer_fn(arch, s, precision)
+    # layers with the same leaves share one compiled draw (``like``)
+    kinds = [arch.layer_leaves(s, li) for li in range(s.layers)]
     with jax.default_matmul_precision("highest"):
         for li in range(s.layers):
-            w = _layer_weights(key, li, s, dtype)
+            w = _layer_weights(key, li, arch, s, kinds.index(kinds[li]),
+                               dtype)
             xs = [layer(w, x, positions, live=lv)
                   for x, lv in zip(xs, lives)]
             del w
@@ -104,9 +105,8 @@ def final_hidden(seed: int, s: Shape, ref_name: str, seqs: List[Served],
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(ref_name: str, s: Shape, precision: str):
-    return jax.jit(functools.partial(reference_module(ref_name).layer, s=s,
-                                     precision=precision))
+def _layer_fn(arch, s: Shape, precision: str):
+    return jax.jit(functools.partial(arch.layer, s=s, precision=precision))
 
 
 def _rows_padded(q: Served) -> np.ndarray:
@@ -141,13 +141,13 @@ def _head(seed: int, s: Shape, dtype):
     return W.lm_head(W.seed_key(seed), s, dtype).astype(jnp.float32)
 
 
-def served_gaps(seed: int, s: Shape, ref_name: str, seqs: List[Served],
+def served_gaps(seed: int, arch, s: Shape, seqs: List[Served],
                 dtype=jnp.bfloat16, length: int = 0) -> List[np.ndarray]:
     """Per sequence, reference best logit minus the served token's
     logit at each served position (>= 0).  ``dtype`` is the one the
     weights are served in; the reference computes in float32 from the
     same rounded values."""
-    hs = final_hidden(seed, s, ref_name, seqs, "f32", dtype, length)
+    hs = final_hidden(seed, arch, s, seqs, "f32", dtype, length)
     head = _head(seed, s, dtype)
     out = []
     for h, q in zip(hs, seqs):
@@ -157,13 +157,13 @@ def served_gaps(seed: int, s: Shape, ref_name: str, seqs: List[Served],
     return out
 
 
-def control_gaps(seed: int, s: Shape, ref_name: str, seqs: List[Served],
+def control_gaps(seed: int, arch, s: Shape, seqs: List[Served],
                  dtype=jnp.bfloat16, length: int = 0) -> List[np.ndarray]:
     """Per sequence, the float32 reference's best logit minus its logit
     for the token the float8 reference puts first, at the same served
     positions (the same prompts and served tokens are fed to both)."""
-    h32 = final_hidden(seed, s, ref_name, seqs, "f32", dtype, length)
-    h8 = final_hidden(seed, s, ref_name, seqs, "f8", dtype, length)
+    h32 = final_hidden(seed, arch, s, seqs, "f32", dtype, length)
+    h8 = final_hidden(seed, arch, s, seqs, "f8", dtype, length)
     head = _head(seed, s, dtype)
     return [_unpad(_control_gap(a, b, head), q)
             for a, b, q in zip(h32, h8, seqs)]

@@ -25,12 +25,14 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
+from functools import cached_property
 from typing import Dict, List, Optional
 
 import numpy as np
 
 from bench import flops as FL
-from bench.model import BENCH, Shape, load_config, load_json, shape
+from bench.model import (BENCH, Shape, load_config, load_json,
+                         reference_module)
 from bench.spans import Spans
 from bench.traffic.source import Arrival, load_mix, make_source
 
@@ -149,6 +151,11 @@ class Run:
     revive_s: Optional[float] = None           # RecoveryReport.total_s
     trace: Optional[object] = None             # bench.trace.Summary
 
+    @cached_property
+    def arch(self):
+        """The configuration's architecture module."""
+        return reference_module(self.cfg["reference"])
+
     def in_window(self, t: float) -> bool:
         return 0.0 <= t < self.seconds
 
@@ -194,7 +201,7 @@ def _observe(run: Run, live: List[ReqRec], step: StepRec, now: float,
     """Book one step: new tokens, queue exits, prefill progress and the
     work the step did, from each live request's host state."""
     from repro.serving.request import RequestState
-    s = run.shape
+    arch, s = run.arch, run.shape
     for r in list(live):
         req = r.req
         if r.left_waiting_t is None and req.state is not RequestState.WAITING:
@@ -211,7 +218,7 @@ def _observe(run: Run, live: List[ReqRec], step: StepRec, now: float,
             if step.traced:
                 step.attn_rows.setdefault((req.dp_rank, "chunk"), []).append(
                     list(range(p0 + 1, p1 + 1)))
-            step.flops += FL.prefill_flops(s, p0, p1)
+            step.flops += FL.prefill_flops(arch, s, p0, p1)
             step.prefill_tokens += p1 - p0
             if from_prefill:         # the last row's logits chose a token
                 step.flops += 2 * s.d_model * s.vocab
@@ -224,7 +231,7 @@ def _observe(run: Run, live: List[ReqRec], step: StepRec, now: float,
                 step.attn_rows.setdefault((req.dp_rank, "decode"),
                                           []).append(ctxs)
             for ctx in ctxs:
-                step.flops += FL.token_flops(s, ctx, True)
+                step.flops += FL.token_flops(arch, s, ctx, True)
             r.token_t.extend([now] * new)
         if req.state in (RequestState.FINISHED, RequestState.FAILED):
             r.done_t = now
@@ -421,7 +428,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
 
     cfg = cfg or load_config(cell["config"])
     mix = mix or load_mix(cell["traffic"])
-    shp = shape(cfg)
+    arch = reference_module(cfg["reference"])
+    shp = arch.shape(cfg)
     peaks = load_json(BENCH / "peaks.json")["devices"]
     kind = devs[0].device_kind
     if platform == "tpu" and kind not in peaks:
@@ -434,10 +442,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
     tmp = tempfile.mkdtemp(prefix="bench-run-")
     phases = {"start": time.perf_counter() - t_process}
     try:
-        pcfg = M.program_config(cfg, shp)
+        pcfg = M.program_config(cfg, arch, shp)
         ecfg = M.engine_config(cfg, seed, os.path.join(tmp, "work"))
         model = Model(pcfg, dtype=jax.numpy.dtype(ecfg.dtype))
-        params = W.program_params(seed, shp, model.vpad,
+        params = W.program_params(seed, arch, shp, model.vpad,
                                   jax.numpy.dtype(ecfg.dtype))
         W.check_layout(params, model.param_specs())
         jax.block_until_ready(params)
@@ -461,8 +469,8 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         from repro.serving.request import RequestState
         failed = sum(r.req.state is RequestState.FAILED for r in attempted)
         picked = sample(run, seed)
-        lost = (shp.experts_lost_with(mix["fault"]["physical_id"])
-                if shp.moe and mix.get("fault") else [])
+        lost = (arch.experts_lost_with(shp, mix["fault"]["physical_id"])
+                if mix.get("fault") else [])
         seqs = []
         for r, ph in picked:
             live = None
@@ -490,7 +498,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         # the reference check
         t_ref = time.perf_counter()
         sq = [check.Served(tok, n, live) for tok, n, live, _ in seqs]
-        gaps = check.served_gaps(seed, shp, cfg["reference"], sq,
+        gaps = check.served_gaps(seed, arch, shp, sq,
                                  jax.numpy.dtype(dep["dtype"]),
                                  dep["max_seq"]) if sq else []
         ref_s = time.perf_counter() - t_ref
@@ -500,7 +508,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool, *,
         ctrl = None
         if control:
             # the control goes through the same comparison, limits and all
-            cg = check.control_gaps(seed, shp, cfg["reference"], sq,
+            cg = check.control_gaps(seed, arch, shp, sq,
                                     jax.numpy.dtype(dep["dtype"]),
                                     dep["max_seq"]) if sq else []
             cc = _checks(cfg, seqs, cg, faulted)

@@ -3,17 +3,20 @@ uses (:class:`Shape`) and into the program's own configuration objects.
 
 A configuration is ``bench/configs/<name>.json``: the published
 ``config.json`` keys (depth cut, listed in ``reduced``), a ``deployment``
-group with the engine's sizes, and the name of its plain reference in
-``bench/reference/``.  Sizes come from the file, and every implementation
-choice of the program keeps its default, with one exception the file
-states in ``deployment``: ``drop_tokens: false`` pins drop-free MoE
-routing, where the published model drops no token and the program's
-default capacity would (a run that serves another model than the file
-states is no sound run to compare).
+group with the engine's sizes, and the name of its architecture module in
+``bench/reference/`` (``reference``), which reads the sizes, lays out
+the weights, counts the operations and holds the plain reference.
+Sizes come from the file, and every implementation choice of the
+program keeps its default, with one exception the file states in
+``deployment``: ``drop_tokens: false`` pins drop-free MoE routing, where
+the published model drops no token and the program's default capacity
+would (a run that serves another model than the file states is no sound
+run to compare).
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 from pathlib import Path
 from typing import Optional
@@ -33,93 +36,36 @@ def load_config(name: str) -> dict:
     return load_json(path)
 
 
-@dataclasses.dataclass(frozen=True)
+def reference_module(name: str):
+    """The architecture module ``bench/reference/<name>.py`` that a
+    configuration file names under ``reference``: the one place that
+    describes the architecture to the benchmark (``bench.reference``)."""
+    return importlib.import_module(f"bench.reference.{name}")
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
 class Shape:
-    """The sizes the yardstick computes with (weights, FLOPs, reference)."""
+    """The sizes the yardstick computes with (weights, FLOPs, reference):
+    the fields the shared code reads.  An architecture module subclasses
+    it with the sizes of its own layers."""
     layers: int
     d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
     vocab: int
     eps: float
-    rope_theta: float
-    d_ff: int = 0                  # dense FFN width (0: every layer MoE)
     experts: int = 0               # routed experts (0: dense model)
-    top_k: int = 0
-    expert_d_ff: int = 0
-    shared_d_ff: int = 0           # all shared experts together
-    redundant: int = 0             # replica slots (deployment)
-    ep_ranks: int = 0              # ranks the expert slots are split over
 
     @property
     def moe(self) -> bool:
         return self.experts > 0
 
-    @property
-    def slots(self) -> int:
-        return self.experts + self.redundant
 
-    def slot_expert(self) -> list:
-        """Logical expert held by each physical slot: slot s < experts
-        holds expert s, the replica slots hold experts 0..redundant-1."""
-        return list(range(self.experts)) + list(range(self.redundant))
-
-    def experts_lost_with(self, rank: int) -> list:
-        """Experts with no slot left once EP rank ``rank`` is gone."""
-        per = self.slots // self.ep_ranks
-        dead = set(range(rank * per, (rank + 1) * per))
-        alive = {e for s, e in enumerate(self.slot_expert()) if s not in dead}
-        return [e for e in range(self.experts) if e not in alive]
-
-
-def shape(cfg: dict) -> Shape:
-    dep = cfg["deployment"]
-    D, H = cfg["hidden_size"], cfg["num_attention_heads"]
-    kw = dict(layers=cfg["num_hidden_layers"], d_model=D, heads=H,
-              kv_heads=cfg["num_key_value_heads"],
-              head_dim=cfg.get("head_dim", D // H),
-              vocab=cfg["vocab_size"], eps=float(cfg["rms_norm_eps"]),
-              rope_theta=float(cfg["rope_theta"]))
-    if cfg.get("num_experts"):
-        kw.update(experts=cfg["num_experts"],
-                  top_k=cfg["num_experts_per_tok"],
-                  expert_d_ff=cfg["moe_intermediate_size"],
-                  shared_d_ff=cfg.get("shared_expert_intermediate_size", 0),
-                  redundant=dep.get("redundant_experts", 0),
-                  ep_ranks=dep["num_dp"])
-    else:
-        kw.update(d_ff=cfg["intermediate_size"])
-    return Shape(**kw)
-
-
-def program_config(cfg: dict, shp: Optional[Shape] = None):
+def program_config(cfg: dict, arch, shp: Optional[Shape] = None):
     """The program's ``ModelConfig``: its registered architecture with the
-    file's sizes put in; implementation choices keep their defaults."""
+    file's sizes put in by the architecture module; implementation
+    choices keep their defaults."""
     from repro.configs import get_config
-    shp = shp or shape(cfg)
-    base = get_config(cfg["arch"])
-    kw = dict(num_layers=shp.layers, d_model=shp.d_model,
-              num_heads=shp.heads, num_kv_heads=shp.kv_heads,
-              head_dim=shp.head_dim, vocab_size=shp.vocab,
-              norm_eps=shp.eps, rope_theta=shp.rope_theta)
-    if shp.moe:
-        if shp.shared_d_ff % shp.expert_d_ff:
-            raise ValueError("shared expert width is not a whole number "
-                             "of expert widths")
-        moe = dict(num_experts=shp.experts, top_k=shp.top_k,
-                   expert_d_ff=shp.expert_d_ff,
-                   num_shared_experts=shp.shared_d_ff // shp.expert_d_ff,
-                   num_redundant_experts=shp.redundant)
-        if cfg["deployment"].get("drop_tokens") is False:
-            # the one choice a file may pin: routing as the published
-            # model routes, every token to its top-k experts (capacity =
-            # tokens x top-k, so no slot can overflow)
-            moe["capacity_factor"] = float(shp.slots)
-        kw["moe"] = dataclasses.replace(base.moe, **moe)
-    else:
-        kw["d_ff"] = shp.d_ff
-    out = dataclasses.replace(base, **kw)
+    out = arch.program_config(cfg, shp or arch.shape(cfg),
+                              get_config(cfg["arch"]))
     out.validate()
     return out
 

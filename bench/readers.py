@@ -5,7 +5,6 @@ from __future__ import annotations
 
 from typing import Optional
 
-from bench import flops as FL
 from bench import trace as TR
 
 
@@ -92,7 +91,7 @@ def kernel_roofline(run, token: str) -> Optional[float]:
     for s in run.steps:
         if s.traced:
             for ctxs in s.attn_rows.values():
-                f, b = FL.paged_attention_call(run.shape, ctxs)
+                f, b = run.arch.attention_call(run.shape, ctxs)
                 need += max(f / pf, b / bw)
     return 100.0 * need * run.shape.layers / spent
 

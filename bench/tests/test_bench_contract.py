@@ -6,7 +6,7 @@ import re
 import pytest
 
 from bench.harness import ROOT, metrics_of
-from bench.model import BENCH
+from bench.model import BENCH, Shape, load_config, reference_module
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -96,6 +96,30 @@ def test_every_name_has_its_file(bench):
     assert configs == {w["config"] for w in bench["workloads"]}
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert (BENCH / "metrics" / f"{m['name']}.py").is_file(), m["name"]
+
+
+# what the shared code calls in an architecture module
+# (bench/reference/__init__.py)
+ARCH_EXPORTS = ("shape", "program_config", "layer_leaves", "program_params",
+                "matmul_params", "attention_flops", "attention_call",
+                "experts_lost_with", "layer")
+
+
+def test_every_reference_module_exports_the_architecture(bench):
+    for c in bench["configs"]:
+        cfg = load_config(c["name"])
+        arch = reference_module(cfg["reference"])
+        missing = [n for n in ARCH_EXPORTS if not callable(
+            getattr(arch, n, None))]
+        assert not missing, (cfg["reference"], missing)
+        s = arch.shape(cfg)
+        assert isinstance(s, Shape) and s.layers >= 1
+        for i in range(s.layers):
+            assert arch.layer_leaves(s, i)
+            assert arch.matmul_params(s, i) > 0
+        assert arch.attention_flops(s, 2) == 2 * arch.attention_flops(s, 1)
+        assert all(0 <= e < s.experts
+                   for e in arch.experts_lost_with(s, 0))
 
 
 def test_each_cell_reports_what_it_must(bench):

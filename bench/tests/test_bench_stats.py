@@ -5,7 +5,7 @@ import pytest
 
 from bench import readers, stats
 from bench.harness import ReqRec, Run, StepRec
-from bench.model import Shape
+from bench.reference.internlm2 import Shape
 from bench.traffic.source import Arrival
 
 SHAPE = Shape(layers=2, d_model=8, heads=2, kv_heads=1, head_dim=4,

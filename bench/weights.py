@@ -6,15 +6,15 @@ layer's weights again without holding the rest, and get the same bits
 the program serves.  Draws are float32 normals times the leaf's scale,
 rounded to the served dtype.
 
-:func:`program_params` lays the same weights out as the program's
-parameter tree (stacked layers, the physical expert bank with its
-replica slots, the vocabulary padded with zero rows) in one jitted call
-on the device.
+Which leaves a layer has, and how they sit in the program's parameter
+tree (its stacks of layers, the physical expert bank with its replica
+slots), the architecture module says (``bench/reference/<name>.py``,
+passed as ``arch``); :func:`program_params` makes the whole tree, the
+vocabulary padded with zero rows, in one jitted call on the device.
 """
 from __future__ import annotations
 
 import zlib
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -39,37 +39,15 @@ def _draw(key, name: str, layer, shape, scale: float, dtype):
     return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
 
 
-def layer_leaves(s: Shape) -> Dict[str, tuple]:
-    """name -> (shape, scale) of one layer's random leaves (norm weights
-    are ones and are not listed)."""
-    D, H, Hkv, Dh = s.d_model, s.heads, s.kv_heads, s.head_dim
-    out = {"wq": ((D, H * Dh), D ** -0.5),
-           "wk": ((D, Hkv * Dh), D ** -0.5),
-           "wv": ((D, Hkv * Dh), D ** -0.5),
-           "wo": ((H * Dh, D), (H * Dh) ** -0.5)}
-    if s.moe:
-        E, F = s.experts, s.expert_d_ff
-        out.update({"router": ((D, E), D ** -0.5),
-                    "gate": ((E, D, F), D ** -0.5),
-                    "up": ((E, D, F), D ** -0.5),
-                    "down": ((E, F, D), F ** -0.5)})
-        if s.shared_d_ff:
-            Fs = s.shared_d_ff
-            out.update({"shared_gate": ((D, Fs), D ** -0.5),
-                        "shared_up": ((D, Fs), D ** -0.5),
-                        "shared_down": ((Fs, D), Fs ** -0.5)})
-    else:
-        F = s.d_ff
-        out.update({"ffn_gate": ((D, F), D ** -0.5),
-                    "ffn_up": ((D, F), D ** -0.5),
-                    "ffn_down": ((F, D), F ** -0.5)})
-    return out
-
-
-def layer_weights(key, s: Shape, layer, dtype=jnp.bfloat16) -> dict:
-    """One layer's weights (logical experts, no replica slots)."""
+def layer_weights(key, arch, s: Shape, layer, dtype=jnp.bfloat16,
+                  like=None) -> dict:
+    """One layer's weights (logical experts, no replica slots): the leaves
+    ``arch.layer_leaves(s, like)`` lists, drawn for ``layer``, and the
+    two norms of a pre-norm layer as ones.  ``like`` is ``layer`` itself,
+    or, where ``layer`` is traced, a static layer of the same kind."""
+    leaves = arch.layer_leaves(s, layer if like is None else like)
     out = {n: _draw(key, n, layer, shp, sc, dtype)
-           for n, (shp, sc) in layer_leaves(s).items()}
+           for n, (shp, sc) in leaves.items()}
     out["ln1"] = jnp.ones((s.d_model,), dtype)
     out["ln2"] = jnp.ones((s.d_model,), dtype)
     return out
@@ -85,41 +63,24 @@ def lm_head(key, s: Shape, dtype=jnp.bfloat16):
     return _draw(key, "lm_head", 0, (s.d_model, s.vocab), EMBED_STD, dtype)
 
 
-def _program_layer(w: dict, s: Shape) -> dict:
-    p = {"ln1": w["ln1"], "ln2": w["ln2"],
-         "mixer": {k: w[k] for k in ("wq", "wk", "wv", "wo")}}
-    if s.moe:
-        slot = jnp.asarray(s.slot_expert(), jnp.int32)
-        p["moe"] = {"router": w["router"], "gate": w["gate"][slot],
-                    "up": w["up"][slot], "down": w["down"][slot]}
-        if s.shared_d_ff:
-            p["moe"]["shared"] = {"w_gate": w["shared_gate"],
-                                  "w_up": w["shared_up"],
-                                  "w_down": w["shared_down"]}
-    else:
-        p["ffn"] = {"w_gate": w["ffn_gate"], "w_up": w["ffn_up"],
-                    "w_down": w["ffn_down"]}
-    return p
-
-
-def program_params(seed: int, s: Shape, padded_vocab: int,
+def program_params(seed: int, arch, s: Shape, padded_vocab: int,
                    dtype=jnp.bfloat16) -> dict:
     """The weights in the program's parameter layout, made on the device
-    in one jitted call (layers one at a time, so the float32 draws of
-    only one layer exist at once)."""
+    in one jitted call; ``arch.program_params`` lays out the layers, one
+    at a time, in the program's stacks."""
     pad = padded_vocab - s.vocab
     if pad < 0:
         raise ValueError("padded vocabulary smaller than the vocabulary")
 
     def make(key):
-        layers = jax.lax.map(
-            lambda i: _program_layer(layer_weights(key, s, i, dtype), s),
-            jnp.arange(s.layers, dtype=jnp.int32))
+        def one_layer(i, like):
+            return layer_weights(key, arch, s, i, dtype, like)
+
         return {"embed": jnp.pad(embed(key, s, dtype), ((0, pad), (0, 0))),
                 "final_norm": jnp.ones((s.d_model,), dtype),
                 "lm_head": jnp.pad(lm_head(key, s, dtype),
                                    ((0, 0), (0, pad))),
-                "layers": layers}
+                **arch.program_params(one_layer, s)}
 
     return jax.jit(make)(seed_key(seed))
 
